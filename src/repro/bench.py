@@ -17,7 +17,40 @@ it without a path dance.
 from __future__ import annotations
 
 import json
+import os
+import re
 import sys
+
+#: The root of the checkout this package is imported from.
+_CHECKOUT = os.path.dirname(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+#: JAX's persistent compile cache when ``JAX_COMPILATION_CACHE_DIR`` is
+#: unset: a fixed directory at the root of the checkout (a temporary or
+#: per-run directory would never be found again).
+COMPILE_CACHE_DIR = os.path.join(_CHECKOUT, ".jax_cache")
+
+
+def setup_compile_cache() -> str:
+    """Turn on JAX's persistent compile cache; call before the first
+    compile, never at import.  A ``JAX_COMPILATION_CACHE_DIR`` set from
+    outside wins (JAX reads it itself and no other directory is set);
+    otherwise the cache lives in :data:`COMPILE_CACHE_DIR`.  Compiles of
+    0.1 s and up are kept: most kernel compiles take under the default
+    1 s threshold.  A Pallas kernel carries its source file's path into
+    the program and so into the cache key: the checkout's root is cut
+    from source paths, so that every checkout shares the entries.
+    Returns the directory in use."""
+    import jax
+
+    jax.config.update("jax_hlo_source_file_canonicalization_regex",
+                      "^" + re.escape(_CHECKOUT + os.sep))
+
+    where = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not where:
+        where = COMPILE_CACHE_DIR
+        jax.config.update("jax_compilation_cache_dir", where)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.1)
+    return where
 
 
 def write_bench_artifact(

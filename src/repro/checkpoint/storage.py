@@ -269,15 +269,16 @@ class StorageCluster:
         blobs: list[bytes | np.ndarray],
         k: int = 4,
         m: int = 2,
-        backend: str = "numpy",
+        backend: str | None = None,
     ) -> list[ObjectLayout]:
         """Batched client-side EC — the ``RS(engine='client')`` plan.
 
         All same-geometry stripes are encoded in *one*
-        ``RSCode.encode_stripes`` call (the PR 2 batched data plane:
-        backend="jax" is a single fused kernel dispatch per chunk-length
-        group), then every data/parity shard is written as an
-        authenticated plain write through the policy engine."""
+        ``RSCode.encode_stripes`` call (backend="jax" is a single fused
+        kernel dispatch per chunk-length group; None takes the platform's
+        data-plane default, the kernels on a TPU), then every data/parity
+        shard is written as an authenticated plain write through the
+        policy engine."""
         from repro.core.erasure import RSCode, split_stripe
 
         arrs = [
@@ -383,7 +384,7 @@ class StorageCluster:
         self,
         layouts: list[ObjectLayout],
         verify: bool = True,
-        backend: str = "numpy",
+        backend: str | None = None,
     ) -> list[bytes]:
         """Batched degraded-capable read through the packet plane.
 
@@ -555,7 +556,7 @@ class StorageCluster:
             layout.parity_coords[idx - len(layout.data_coords)] = coord
 
     @staticmethod
-    def _decode_shard_group(code, shard_lists, pattern, backend="numpy"):
+    def _decode_shard_group(code, shard_lists, pattern, backend=None):
         """Stack each slot's per-member shards into an (S, L) batch and
         reconstruct the whole (geometry, chunk, erasure-pattern) group in
         ONE ``decode_stripes`` call.  Returns (batched_slots, (S, k, L))."""
@@ -667,7 +668,7 @@ class StorageCluster:
                 code, [shards for _, _, shards in members], pattern)
             parm = None
             if any(idx >= k for _, idx, _ in members):
-                parm = code.encode_stripes(datam, backend="numpy")
+                parm = code.encode_stripes(datam)
             for s, (layout, idx, _) in enumerate(members):
                 rebuilt = datam[s, idx] if idx < k else parm[s, idx - k]
                 tasks.append((layout, idx, rebuilt))

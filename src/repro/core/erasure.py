@@ -61,13 +61,15 @@ class RSCode:
 
     # -- whole-stripe paths ------------------------------------------------
 
-    def encode(self, data: np.ndarray, backend: str = "numpy") -> np.ndarray:
+    def encode(self, data: np.ndarray, backend: str | None = None) -> np.ndarray:
         """(k, L) data bytes -> (m, L) parity bytes.
 
         backend="numpy" uses the host LUT path (the paper's per-byte table
         walk, vectorized); backend="jax" routes through kernels/ops.py
-        (bit-sliced, Pallas on TPU / interpret on CPU).
+        (bit-sliced, Pallas on TPU / interpret on CPU); None takes the
+        platform's data-plane default (:func:`_backend`).
         """
+        backend = _backend(backend)
         data = np.asarray(data, dtype=np.uint8)
         if data.shape[0] != self.k:
             raise ValueError(f"expected {self.k} data chunks, got {data.shape[0]}")
@@ -85,12 +87,15 @@ class RSCode:
             )
         raise ValueError(f"unknown backend {backend!r}")
 
-    def encode_stripes(self, data: np.ndarray, backend: str = "jax") -> np.ndarray:
+    def encode_stripes(
+        self, data: np.ndarray, backend: str | None = None
+    ) -> np.ndarray:
         """Batched encode: (S, k, L) data -> (S, m, L) parity.
 
         backend="jax" is one fused kernel dispatch for the whole batch
         (kernels/ops.py); backend="numpy" is the vectorized host LUT path.
         """
+        backend = _backend(backend)
         data = np.asarray(data, dtype=np.uint8)
         if data.ndim != 3 or data.shape[1] != self.k:
             raise ValueError(f"expected (S, {self.k}, L) stripes, got {data.shape}")
@@ -112,7 +117,7 @@ class RSCode:
     def decode(
         self,
         shards: Sequence[np.ndarray | None],
-        backend: str = "numpy",
+        backend: str | None = None,
     ) -> np.ndarray:
         """Reconstruct (k, L) data from any >= k surviving shards.
 
@@ -133,7 +138,7 @@ class RSCode:
         sub = self.generator[rows]  # (k, k) — invertible because MDS
         inv = gf256.gf_mat_inv(sub)
         stacked = np.stack([np.asarray(shards[i], dtype=np.uint8) for i in rows])
-        if backend == "jax":
+        if _backend(backend) == "jax":
             from repro.kernels import ops
 
             return np.asarray(ops.gf_matmul_bytes(inv, stacked, block_w=None))
@@ -142,7 +147,7 @@ class RSCode:
     def decode_stripes(
         self,
         shards: Sequence[np.ndarray | None],
-        backend: str = "jax",
+        backend: str | None = None,
     ) -> np.ndarray:
         """Batched decode: reconstruct (S, k, L) data from surviving shards.
 
@@ -168,7 +173,7 @@ class RSCode:
         stacked = np.stack(
             [np.asarray(shards[i], dtype=np.uint8) for i in rows], axis=1
         )  # (S, k, L)
-        if backend == "jax":
+        if _backend(backend) == "jax":
             from repro.kernels import ops
 
             return np.asarray(ops.gf_matmul_bytes_batched(inv, stacked))
@@ -185,6 +190,15 @@ class RSCode:
         if index < self.k:
             return data[index]
         return gf256.gf_matmul(self.parity_matrix[index - self.k : index - self.k + 1], data)[0]
+
+
+def _backend(backend: str | None) -> str:
+    """The caller's backend, else the platform's data-plane default: the
+    Pallas kernels on a TPU, the numpy LUT path elsewhere
+    (``repro.kernels.ops.dataplane_backend``)."""
+    from repro.kernels.ops import dataplane_backend
+
+    return dataplane_backend(backend)
 
 
 _PARITY_CACHE: dict[tuple[int, int, str], np.ndarray] = {}
@@ -342,7 +356,7 @@ def stream_encode(
     packet_payload: int,
     pool_size: int = 64,
     interleaved: bool = True,
-    backend: str = "numpy",
+    backend: str | None = None,
 ) -> np.ndarray:
     """End-to-end streaming TriEC encode of a (k, L) stripe — batched.
 
@@ -385,7 +399,7 @@ def stream_encode(
     padded = np.zeros((k, npkts * packet_payload), dtype=np.uint8)
     padded[:, :length] = data
     parity_mat = code.parity_matrix
-    if backend == "jax":
+    if _backend(backend) == "jax":
         from repro.kernels import ops
 
         # Stage 1, one dispatch: every (parity, chunk) intermediate stream

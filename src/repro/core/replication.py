@@ -233,7 +233,9 @@ def replicate(
         strategy=strategy,
         axis_size=axis_size,
     )
-    from repro.parallel.compat import shard_map
-
     spec = P(axis_name)
-    return shard_map(fn, mesh=mesh, in_specs=spec, out_specs=spec)(x)
+    # under jit: an eager shard_map call on a mesh with Explicit axes (what
+    # jax.make_mesh builds) is placed on one device and refused
+    return jax.jit(
+        jax.shard_map(fn, mesh=mesh, in_specs=spec, out_specs=spec)
+    )(x)

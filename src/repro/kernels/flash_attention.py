@@ -86,7 +86,8 @@ def flash_attention_fwd(
     causal: bool = True,
     bq: int = 128,
     bk: int = 512,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> jax.Array:
     b, s, h, d = q.shape
     hkv, dv = k.shape[2], v.shape[-1]

@@ -12,15 +12,17 @@ therefore advances 32 bytes x lane-width of payload, vs. one byte per LUT
 step — the TPU-native re-expression of the paper's per-packet encode loop.
 
 Tiling: the word axis ``w`` is the minor (lane) dimension, tiled in
-``block_w``-word VMEM blocks; the full (m, k, 8, 8) coefficient bit-matrix
-tensor rides along each grid step (it is tiny: <= 8*8*64 B).  Per grid step
-the kernel touches k*8*block_w*4 input bytes and m*8*block_w*4 output bytes
-— with the default block_w=1024 and RS(6,3) that is 192 KiB in / 96 KiB out,
-comfortably inside VMEM, with the (8, 128)-aligned (sublane, lane) layout
-the VPU wants.
+``block_w``-word VMEM blocks; the coefficients ride along each grid step
+as a (k, 8, m*8, 1) tensor of 0x0/0xFFFFFFFF masks, one column per input
+bit-plane.  Per grid step the kernel touches k*8*block_w*4 input bytes and
+m*8*block_w*4 output bytes — with block_w=2048 and RS(6,3) that is
+384 KiB in / 192 KiB out, inside VMEM, with the (8, 128)-aligned
+(sublane, lane) layout the VPU wants.  The body only slices refs at
+static indices: Mosaic has no general gather.
 
 Validated in interpret mode against ``ref.gf_matmul_bitsliced_ref`` and the
-byte-domain oracle across shape/dtype sweeps (tests/test_kernels.py).
+byte-domain oracle across shape/dtype sweeps (tests/test_kernels.py), and
+compiled for a TPU v5e in tests/test_tpu_compile.py.
 """
 
 from __future__ import annotations
@@ -32,90 +34,38 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-def _xor_fold(x: jax.Array, axis: int) -> jax.Array:
-    """Log-depth pairwise XOR reduction over ``axis`` (size a power of two)."""
-    n = x.shape[axis]
-    while n > 1:
-        half = n // 2
-        lo = jax.lax.slice_in_dim(x, 0, half, axis=axis)
-        hi = jax.lax.slice_in_dim(x, half, n, axis=axis)
-        x = lo ^ hi
-        n = half
-    return jnp.squeeze(x, axis=axis)
+def _masks(bitmat: jax.Array) -> jax.Array:
+    """(m, k, 8_out, 8_in) 0/1 bit-matrices -> (k, 8_in, m*8_out, 1) masks.
+
+    Each (chunk j, input bit ib) gets one column of 0x0/0xFFFFFFFF words,
+    one row per output (parity i, bit ob): the kernel ANDs it, broadcast
+    along lanes, with input plane (j, ib) broadcast along sublanes.  Built
+    by XLA outside the kernel (it is tiny), so the kernel body only slices
+    refs at static indices — Mosaic lowers no gather."""
+    m, k = bitmat.shape[0], bitmat.shape[1]
+    masks = jnp.uint32(0) - bitmat.astype(jnp.uint32)
+    return masks.transpose(1, 3, 0, 2).reshape(k, 8, m * 8, 1)
 
 
-def _gf_bitsliced_body(bitmat: jax.Array, planes: jax.Array, *, m: int, k: int) -> jax.Array:
-    """(k, 8, block_w) planes x (m, k, 8, 8) bit-matrices -> (m, 8, block_w).
+def _gf_bitsliced_body(masks_ref, planes_ref, chunks) -> jax.Array:
+    """(k, 8, block_w) planes x (k, 8, m*8, 1) masks -> (m*8, block_w).
 
-    Fully vectorized: per input chunk ``j`` one broadcast mask-tensor AND of
-    shape (m, 8, 8, block_w) followed by a log-depth XOR fold over the
-    input-bit axis — k VPU-wide ops instead of the m*8*k*8 scalar-indexed
-    AND/XOR unroll this replaced.  Masks are 0x0/0xFFFFFFFF words derived
-    branchlessly from the coefficient bits.
-    """
-    masks = jnp.uint32(0) - bitmat  # (m, k, 8, 8): bit -> all-ones mask
-    acc = jnp.zeros((m, 8, planes.shape[-1]), dtype=jnp.uint32)
-    for j in range(k):
-        # (m, 8_out, 8_in, 1) & (8_in, block_w) -> (m, 8_out, 8_in, block_w)
-        masked = masks[:, j, :, :, None] & planes[j][None, None, :, :]
-        acc = acc ^ _xor_fold(masked, axis=2)
+    Output row (i, ob) is XOR_{j in chunks, ib} mask[j, ib, (i, ob)] &
+    plane[j, ib]: 8 full-tile AND+XOR steps over (m*8, block_w) per chunk,
+    with no fold and no relayout.  ``planes_ref`` is a (k, 8, block_w)
+    view of the tile."""
+    acc = None
+    for j in chunks:
+        masks, plane = masks_ref[j], planes_ref[j]  # (8, m*8, 1), (8, block_w)
+        for ib in range(8):
+            term = masks[ib] & plane[ib:ib + 1]
+            acc = term if acc is None else acc ^ term
     return acc
 
 
-def _gf_bitsliced_kernel(bitmat_ref, planes_ref, out_ref, *, m: int, k: int):
-    """One grid step: (k, 8, block_w) planes x (m, k, 8, 8) -> (m, 8, block_w)."""
-    out_ref[...] = _gf_bitsliced_body(
-        bitmat_ref[...], planes_ref[...], m=m, k=k
-    )
-
-
-def _gf_bitsliced_batched_kernel(bitmat_ref, planes_ref, out_ref, *, m: int, k: int):
-    """One (stripe, word-block) grid step: (1, k, 8, block_w) -> (1, m, 8, block_w)."""
-    out_ref[...] = _gf_bitsliced_body(
-        bitmat_ref[...], planes_ref[...][0], m=m, k=k
-    )[None]
-
-
-@functools.partial(
-    jax.jit, static_argnames=("m", "k", "block_w", "interpret")
-)
-def gf_matmul_bitsliced(
-    bitmat: jax.Array,
-    planes: jax.Array,
-    *,
-    m: int,
-    k: int,
-    block_w: int = 1024,
-    interpret: bool = True,
-) -> jax.Array:
-    """Pallas bit-sliced GF(2^8) matmul.
-
-    Args:
-      bitmat: (m, k, 8, 8) uint32 0/1 coefficient bit-matrices.
-      planes: (k, 8, w) uint32 input bit-planes; w % block_w == 0.
-      m, k: static code dimensions.
-      block_w: words per VMEM tile (lane-dim multiple of 128 on TPU).
-      interpret: run the kernel body in Python on CPU (validation mode).
-
-    Returns:
-      (m, 8, w) uint32 output bit-planes.
-    """
-    kk, eight, w = planes.shape
-    assert kk == k and eight == 8, planes.shape
-    assert bitmat.shape == (m, k, 8, 8), bitmat.shape
-    assert w % block_w == 0, (w, block_w)
-    grid = (w // block_w,)
-    return pl.pallas_call(
-        functools.partial(_gf_bitsliced_kernel, m=m, k=k),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((m, k, 8, 8), lambda i: (0, 0, 0, 0)),
-            pl.BlockSpec((k, 8, block_w), lambda i: (0, 0, i)),
-        ],
-        out_specs=pl.BlockSpec((m, 8, block_w), lambda i: (0, 0, i)),
-        out_shape=jax.ShapeDtypeStruct((m, 8, w), jnp.uint32),
-        interpret=interpret,
-    )(bitmat.astype(jnp.uint32), planes)
+def _gf_bitsliced_batched_kernel(masks_ref, planes_ref, out_ref, *, k: int):
+    """One (stripe, word-block) grid step: (1, k, 8, block_w) -> (1, m*8, block_w)."""
+    out_ref[0] = _gf_bitsliced_body(masks_ref, planes_ref.at[0], range(k))
 
 
 @functools.partial(
@@ -128,7 +78,7 @@ def gf_matmul_bitsliced_batched(
     m: int,
     k: int,
     block_w: int = 1024,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Batched bit-sliced GF(2^8) matmul: one dispatch for a stripe batch.
 
@@ -153,29 +103,27 @@ def gf_matmul_bitsliced_batched(
     assert bitmat.shape == (m, k, 8, 8), bitmat.shape
     assert w % block_w == 0, (w, block_w)
     grid = (s, w // block_w)
-    return pl.pallas_call(
-        functools.partial(_gf_bitsliced_batched_kernel, m=m, k=k),
+    out = pl.pallas_call(
+        functools.partial(_gf_bitsliced_batched_kernel, k=k),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((m, k, 8, 8), lambda si, wi: (0, 0, 0, 0)),
+            pl.BlockSpec((k, 8, m * 8, 1), lambda si, wi: (0, 0, 0, 0)),
             pl.BlockSpec((1, k, 8, block_w), lambda si, wi: (si, 0, 0, wi)),
         ],
-        out_specs=pl.BlockSpec((1, m, 8, block_w), lambda si, wi: (si, 0, 0, wi)),
-        out_shape=jax.ShapeDtypeStruct((s, m, 8, w), jnp.uint32),
+        out_specs=pl.BlockSpec((1, m * 8, block_w), lambda si, wi: (si, 0, wi)),
+        out_shape=jax.ShapeDtypeStruct((s, m * 8, w), jnp.uint32),
         interpret=interpret,
-    )(bitmat.astype(jnp.uint32), planes)
+    )(_masks(bitmat), planes)
+    return out.reshape(s, m, 8, w)
 
 
-def _gf_scale_kernel(bitmat_ref, planes_ref, out_ref, *, m: int, k: int):
+def _gf_scale_kernel(masks_ref, planes_ref, out_ref, *, k: int):
     """One grid step of the stream-scaling (TriEC data-node) stage:
-    out[i, j] = g[i, j] * chunk_j — the bit-sliced matmul body *without*
-    the fold over chunks, so every (parity, chunk) intermediate stream
-    survives for downstream parity-node aggregation."""
-    planes = planes_ref[...]                    # (k, 8, block_w)
-    masks = jnp.uint32(0) - bitmat_ref[...]     # (m, k, 8, 8)
+    out[j] = g[:, j] * chunk_j — the bit-sliced matmul body run once per
+    chunk, *without* the fold over chunks, so every (parity, chunk)
+    intermediate stream survives for downstream parity-node aggregation."""
     for j in range(k):
-        masked = masks[:, j, :, :, None] & planes[j][None, None, :, :]
-        out_ref[:, j, :, :] = _xor_fold(masked, axis=2)
+        out_ref[j] = _gf_bitsliced_body(masks_ref, planes_ref, (j,))
 
 
 @functools.partial(
@@ -188,7 +136,7 @@ def gf_scale_bitsliced(
     m: int,
     k: int,
     block_w: int = 1024,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Bit-sliced GF(2^8) constant-multiply of k chunks by an (m, k)
     coefficient grid: (k, 8, w) planes -> (m, k, 8, w) scaled streams.
@@ -203,17 +151,18 @@ def gf_scale_bitsliced(
     assert bitmat.shape == (m, k, 8, 8), bitmat.shape
     assert w % block_w == 0, (w, block_w)
     grid = (w // block_w,)
-    return pl.pallas_call(
-        functools.partial(_gf_scale_kernel, m=m, k=k),
+    out = pl.pallas_call(
+        functools.partial(_gf_scale_kernel, k=k),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((m, k, 8, 8), lambda i: (0, 0, 0, 0)),
+            pl.BlockSpec((k, 8, m * 8, 1), lambda i: (0, 0, 0, 0)),
             pl.BlockSpec((k, 8, block_w), lambda i: (0, 0, i)),
         ],
-        out_specs=pl.BlockSpec((m, k, 8, block_w), lambda i: (0, 0, 0, i)),
-        out_shape=jax.ShapeDtypeStruct((m, k, 8, w), jnp.uint32),
+        out_specs=pl.BlockSpec((k, m * 8, block_w), lambda i: (0, 0, i)),
+        out_shape=jax.ShapeDtypeStruct((k, m * 8, w), jnp.uint32),
         interpret=interpret,
-    )(bitmat.astype(jnp.uint32), planes)
+    )(_masks(bitmat), planes)
+    return out.reshape(k, m, 8, w).transpose(1, 0, 2, 3)
 
 
 # ---------------------------------------------------------------------------
@@ -242,7 +191,7 @@ def gf_matmul_mxu(
     bits: jax.Array,
     *,
     block_n: int = 512,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """MXU-path GF(2) matmul: (8m, 8k) x (8k, n) -> (8m, n) over bits.
 

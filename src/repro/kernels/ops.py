@@ -4,6 +4,8 @@ These own padding, bit-plane packing, and backend dispatch: on TPU the
 kernels compile natively; everywhere else they run in interpret mode
 (exact same kernel body, Python-executed), so the whole framework is
 testable on CPU.  ``backend="ref"`` routes to the pure-jnp oracles.
+:func:`dataplane_backend` picks the RS data plane's default backend from
+the platform: the kernels on a TPU, the numpy LUT path elsewhere.
 """
 
 from __future__ import annotations
@@ -21,7 +23,6 @@ from repro.kernels.gf256_encode import (
     gf_matmul_mxu,
     gf_scale_bitsliced,
 )
-from repro.kernels.xor_reduce import xor_reduce as _xor_reduce_kernel
 from repro.kernels.xor_reduce import xor_reduce_batched as _xor_reduce_batched
 
 
@@ -31,6 +32,16 @@ def _on_tpu() -> bool:
 
 def _interpret() -> bool:
     return not _on_tpu()
+
+
+def dataplane_backend(backend: str | None = None) -> str:
+    """The RS data plane's backend: ``backend`` when the caller names one,
+    else the Pallas kernels (``"jax"``) on a TPU and the numpy LUT path
+    (``"numpy"``) on any other platform, where the kernels would only be
+    interpreted."""
+    if backend is not None:
+        return backend
+    return "jax" if _on_tpu() else "numpy"
 
 
 # ---------------------------------------------------------------------------
@@ -77,46 +88,89 @@ def _pick_block_w(length: int, block_w: int | None) -> int:
     return block_w if block_w is not None else _clamp_block_w(-(-length // 32))
 
 
-@functools.partial(jax.jit, static_argnames=("block_w",))
-def _encode_planes_batched(bitmat, data_bytes, block_w):
+@functools.partial(jax.jit, static_argnames=("block_w", "interpret"))
+def _encode_planes_batched(bitmat, data_bytes, block_w, interpret):
     """Fused pipeline under one jit: bit-plane pack -> single batched Pallas
     dispatch over the (stripe, word-block) grid -> unpack."""
     planes = ref.pack_bitplanes(data_bytes)          # (S, k, 8, w)
     m, k = bitmat.shape[0], bitmat.shape[1]
     out_planes = gf_matmul_bitsliced_batched(
-        bitmat, planes, m=m, k=k, block_w=block_w, interpret=_interpret()
+        bitmat, planes, m=m, k=k, block_w=block_w, interpret=interpret
     )
     return ref.unpack_bitplanes(out_planes)          # (S, m, L)
 
 
+#: Payload bytes, (k + n) * L per stripe, that one fused dispatch may
+#: carry.  XLA materialises the bit-plane pack and unpack as uint32
+#: temporaries in HBM, 66-96x the dispatch's output bytes in the programs
+#: compiled for a v5e: one dispatch of a 6x6 decode over 64 stripes of
+#: 1 MiB would need 24.75 GiB, more than the chip's 16 GB.
+_DISPATCH_BYTES = 64 << 20
+
+
+def _dispatch_sizes(s: int, per_stripe: int) -> list[int]:
+    """Split an S-stripe batch into power-of-two dispatches, largest
+    first, each of at most ``_DISPATCH_BYTES`` (and at least one stripe):
+    whatever S callers send, a (geometry, chunk length) compiles at most
+    log2(cap) + 1 programs."""
+    cap = 1 << (max(1, _DISPATCH_BYTES // max(per_stripe, 1)).bit_length() - 1)
+    sizes = []
+    while s > 0:
+        size = min(cap, 1 << (s.bit_length() - 1))
+        sizes.append(size)
+        s -= size
+    return sizes
+
+
 def gf_matmul_bytes_batched(
     coeffs: np.ndarray | jax.Array,
-    data: jax.Array,
+    data: np.ndarray | jax.Array,
     backend: str = "pallas",
     block_w: int | None = None,
 ) -> jax.Array:
     """(n, k) GF coefficient bytes x (S, k, L) stripe batch -> (S, n, L).
 
-    The batched workhorse: S concurrent stripes share one coefficient
-    upload and one fused pack/matmul/unpack dispatch instead of S
-    per-stripe round trips.  ``block_w=None`` picks the tile adaptively
-    from L (multiple of 8 words, capped at 2048 lanes).
+    The batched workhorse: stripes share one coefficient upload and one
+    fused pack/matmul/unpack dispatch per :func:`_dispatch_sizes` piece
+    instead of S per-stripe round trips; host arrays move to the device
+    one piece at a time.  ``block_w=None`` picks the tile adaptively from
+    L (a multiple of the lane granule, capped at 2048 words).
     """
-    data = jnp.asarray(data, dtype=jnp.uint8)
+    if not isinstance(data, jax.Array):
+        data = np.asarray(data, dtype=np.uint8)
     assert data.ndim == 3, data.shape
     coeffs_np = np.ascontiguousarray(coeffs, dtype=np.uint8)
     n, k = coeffs_np.shape
-    assert data.shape[1] == k, (coeffs_np.shape, data.shape)
-    if n == 0:
-        return jnp.zeros((data.shape[0], 0, data.shape[2]), dtype=jnp.uint8)
+    s, kk, length = data.shape
+    assert kk == k, (coeffs_np.shape, data.shape)
+    if n == 0 or s == 0:
+        return jnp.zeros((s, n, length), dtype=jnp.uint8)
     if backend == "ref":
-        return ref.gf_matmul_batched_ref(jnp.asarray(coeffs_np), data)
-    bw = _pick_block_w(data.shape[2], block_w)
-    # Pad L so the packed word count divides the kernel block.
-    data_p, orig = _pad_to(data, 32 * bw, axis=2)
+        return ref.gf_matmul_batched_ref(
+            jnp.asarray(coeffs_np), jnp.asarray(data, dtype=jnp.uint8))
+    bw = _pick_block_w(length, block_w)
     bitmat = _bitmat_device(coeffs_np.tobytes(), n, k)
-    out = _encode_planes_batched(bitmat, data_p, bw)
-    return out[:, :, :orig]
+    outs, lo = [], 0
+    for size in _dispatch_sizes(s, (k + n) * length):
+        piece = jnp.asarray(data[lo:lo + size], dtype=jnp.uint8)
+        # Pad L so the packed word count divides the kernel block.
+        piece, _ = _pad_to(piece, 32 * bw, axis=2)
+        out = _encode_planes_batched(bitmat, piece, bw, _interpret())
+        outs.append(out[:, :, :length])
+        lo += size
+    return outs[0] if len(outs) == 1 else jnp.concatenate(outs)
+
+
+def gf_matmul_program(n: int, k: int, s: int, length: int, sharding=None):
+    """The fused pack/matmul/unpack program :func:`gf_matmul_bytes_batched`
+    dispatches for an (n, k) coefficient matrix and an (s, k, L) piece,
+    lowered for this platform (or for ``sharding``'s device) but not run:
+    its text names ``tpu_custom_call`` when the kernel compiles natively."""
+    bw = _pick_block_w(length, None)
+    padded = -(-length // (32 * bw)) * 32 * bw
+    bitmat = jax.ShapeDtypeStruct((n, k, 8, 8), jnp.uint32, sharding=sharding)
+    data = jax.ShapeDtypeStruct((s, k, padded), jnp.uint8, sharding=sharding)
+    return _encode_planes_batched.lower(bitmat, data, bw, _interpret())
 
 
 def rs_encode_stripes(
@@ -137,13 +191,13 @@ def rs_encode_stripes(
     return gf_matmul_bytes_batched(parity, data, backend=backend, block_w=block_w)
 
 
-@functools.partial(jax.jit, static_argnames=("block_w",))
-def _scale_planes(bitmat, data_bytes, block_w):
+@functools.partial(jax.jit, static_argnames=("block_w", "interpret"))
+def _scale_planes(bitmat, data_bytes, block_w, interpret):
     """Fused pack -> bit-sliced stream scaling -> unpack, one jit."""
     planes = ref.pack_bitplanes(data_bytes)          # (k, 8, w)
     m, k = bitmat.shape[0], bitmat.shape[1]
     out_planes = gf_scale_bitsliced(
-        bitmat, planes, m=m, k=k, block_w=block_w, interpret=_interpret()
+        bitmat, planes, m=m, k=k, block_w=block_w, interpret=interpret
     )
     return ref.unpack_bitplanes(out_planes)          # (m, k, L)
 
@@ -169,7 +223,7 @@ def gf_scale_streams(
     bw = _pick_block_w(data.shape[1], block_w)
     data_p, orig = _pad_to(data, 32 * bw, axis=1)
     bitmat = _bitmat_device(coeffs_np.tobytes(), m, k)
-    out = _scale_planes(bitmat, data_p, bw)
+    out = _scale_planes(bitmat, data_p, bw, _interpret())
     return out[:, :, :orig]
 
 
@@ -246,21 +300,11 @@ def xor_reduce_bytes(x: jax.Array, backend: str = "pallas") -> jax.Array:
 
     Odd-sized payloads are zero-padded to uint32 word granularity and
     sliced back, so every L stays on the kernel path (XOR of zero is a
-    no-op; previously L % 4 != 0 silently fell back to the jnp ref path).
-    """
+    no-op)."""
     x = jnp.asarray(x, dtype=jnp.uint8)
     if backend == "ref":
         return ref.xor_reduce_ref(x)
-    n, L = x.shape
-    xp, _ = _pad_to(x, 4, axis=1)
-    words = jax.lax.bitcast_convert_type(
-        xp.reshape(n, -1, 4), jnp.uint32
-    ).reshape(n, -1)
-    bw = _clamp_block_w(words.shape[1])
-    words_p, orig = _pad_to(words, bw, axis=1)
-    out = _xor_reduce_kernel(words_p, block_w=bw, interpret=_interpret())[:orig]
-    out_bytes = jax.lax.bitcast_convert_type(out[:, None], jnp.uint8)
-    return out_bytes.reshape(-1)[:L]
+    return xor_reduce_bytes_batched(x[None], backend=backend)[0]
 
 
 def xor_reduce_bytes_batched(x: jax.Array, backend: str = "pallas") -> jax.Array:

@@ -16,52 +16,28 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-def _xor_tree(x, n: int):
-    """Log-depth XOR fold of rows x[0..n-1] (better ILP than a serial fold)."""
-    vals = [x[i] for i in range(n)]
+def _xor_reduce_batched_kernel(x_ref, out_ref, *, n: int):
+    """(1, n, block_w) tile -> (1, 1, block_w): log-depth XOR fold of the n
+    rows (better ILP than a serial fold), each row a static ref slice."""
+    vals = [x_ref[0, pl.ds(i, 1), :] for i in range(n)]
     while len(vals) > 1:
         nxt = [vals[i] ^ vals[i + 1] for i in range(0, len(vals) - 1, 2)]
         if len(vals) % 2:
             nxt.append(vals[-1])
         vals = nxt
-    return vals[0]
-
-
-def _xor_reduce_kernel(x_ref, out_ref, *, n: int):
-    out_ref[...] = _xor_tree(x_ref[...], n)  # (n, block_w) -> (block_w,)
-
-
-def _xor_reduce_batched_kernel(x_ref, out_ref, *, n: int):
-    out_ref[...] = _xor_tree(x_ref[...][0], n)[None]  # (1, n, bw) -> (1, bw)
-
-
-@functools.partial(jax.jit, static_argnames=("block_w", "interpret"))
-def xor_reduce(
-    x: jax.Array, *, block_w: int = 2048, interpret: bool = True
-) -> jax.Array:
-    """XOR-fold (n, w) uint32 over axis 0 -> (w,) uint32. w % block_w == 0."""
-    n, w = x.shape
-    assert w % block_w == 0, (w, block_w)
-    grid = (w // block_w,)
-    return pl.pallas_call(
-        functools.partial(_xor_reduce_kernel, n=n),
-        grid=grid,
-        in_specs=[pl.BlockSpec((n, block_w), lambda i: (0, i))],
-        out_specs=pl.BlockSpec((block_w,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((w,), jnp.uint32),
-        interpret=interpret,
-    )(x)
+    out_ref[0] = vals[0]
 
 
 @functools.partial(jax.jit, static_argnames=("block_w", "interpret"))
 def xor_reduce_batched(
-    x: jax.Array, *, block_w: int = 2048, interpret: bool = True
+    x: jax.Array, *, block_w: int = 2048, interpret: bool
 ) -> jax.Array:
     """Batched XOR fold: (S, n, w) uint32 over axis 1 -> (S, w) uint32.
 
     One dispatch over a 2D (batch, word-block) grid — the parity-node
     aggregation for S concurrent sequences in a single kernel launch.
-    w % block_w == 0.
+    w % block_w == 0.  The output block is (1, 1, block_w) of an (S, 1, w)
+    array, so its last two dimensions meet the TPU's (8, 128) tiling rule.
     """
     s, n, w = x.shape
     assert w % block_w == 0, (w, block_w)
@@ -70,7 +46,7 @@ def xor_reduce_batched(
         functools.partial(_xor_reduce_batched_kernel, n=n),
         grid=grid,
         in_specs=[pl.BlockSpec((1, n, block_w), lambda si, wi: (si, 0, wi))],
-        out_specs=pl.BlockSpec((1, block_w), lambda si, wi: (si, wi)),
-        out_shape=jax.ShapeDtypeStruct((s, w), jnp.uint32),
+        out_specs=pl.BlockSpec((1, 1, block_w), lambda si, wi: (si, 0, wi)),
+        out_shape=jax.ShapeDtypeStruct((s, 1, w), jnp.uint32),
         interpret=interpret,
-    )(x)
+    )(x)[:, 0]

@@ -227,9 +227,7 @@ def moe_ep_apply(
 
     d_axes = tuple(data_axes)
     x_spec = P(d_axes, model_axis, None)
-    from repro.parallel.compat import shard_map
-
-    out = shard_map(
+    out = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(

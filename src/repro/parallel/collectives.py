@@ -112,9 +112,7 @@ def make_ring_collective(fn, mesh, axis_name: str):
     else:
         in_spec, out_spec = P(), P()
 
-    from repro.parallel.compat import shard_map
-
     return jax.jit(
-        shard_map(body, mesh=mesh, in_specs=in_spec, out_specs=out_spec,
-                  check_vma=False)
+        jax.shard_map(body, mesh=mesh, in_specs=in_spec, out_specs=out_spec,
+                      check_vma=False)
     )

@@ -70,7 +70,7 @@ def test_decode_stripes_roundtrip_random_erasures(km, length, rnd):
     rng = np.random.default_rng(rnd.randint(0, 2**31))
     s = rng.integers(1, 5)
     data = rng.integers(0, 256, (s, k, length), dtype=np.uint8)
-    parity = code.encode_stripes(data)
+    parity = code.encode_stripes(data, backend="jax")
     shards = [data[:, i] for i in range(k)] + [parity[:, i] for i in range(m)]
     lost = rnd.sample(range(k + m), m)
     degraded = [None if i in lost else shards[i] for i in range(k + m)]
@@ -183,3 +183,27 @@ def test_parity_bitmatrix_memoized():
     assert not a.flags.writeable
     code = RSCode(3, 2)
     assert code.parity_bitmatrix is a
+
+
+@pytest.mark.parametrize("s,per_stripe", [(1, 9 << 20), (86, 9 << 20),
+                                          (7, 12 << 20), (1000, 36 << 10)])
+def test_dispatch_sizes_power_of_two_within_budget(s, per_stripe):
+    sizes = ops._dispatch_sizes(s, per_stripe)
+    assert sum(sizes) == s and sizes == sorted(sizes, reverse=True)
+    assert all(n & (n - 1) == 0 for n in sizes)
+    assert all(n == 1 or n * per_stripe <= ops._DISPATCH_BYTES for n in sizes)
+
+
+def test_split_batch_matches_oracle(monkeypatch):
+    """A batch split over several dispatches (two stripes each here)
+    equals the LUT oracle, encode and decode."""
+    k, m, length = 6, 3, 64
+    monkeypatch.setattr(ops, "_DISPATCH_BYTES", 2 * (k + k) * length)
+    code = RSCode(k, m)
+    rng = np.random.default_rng(11)
+    data = rng.integers(0, 256, (7, k, length), dtype=np.uint8)
+    parity = code.encode_stripes(data, backend="jax")
+    assert np.array_equal(parity, code.encode_stripes(data, backend="numpy"))
+    shards = [data[:, i] for i in range(k)] + [parity[:, i] for i in range(m)]
+    degraded = [None if i in (0, 4, 5) else s for i, s in enumerate(shards)]
+    assert np.array_equal(code.decode_stripes(degraded, backend="jax"), data)

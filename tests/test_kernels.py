@@ -19,8 +19,8 @@ except ImportError:  # fall back to the deterministic local shim
 from repro.core.erasure import RSCode
 from repro.kernels import ops, ref
 from repro.kernels.gf256_encode import (
-    gf_matmul_bitsliced,
     gf_matmul_bitsliced_batched,
+    gf_scale_bitsliced,
 )
 from repro.kernels.xor_reduce import xor_reduce_batched
 
@@ -55,6 +55,8 @@ def test_rs_encode_mxu_variant(k, m):
 
 
 def test_bitsliced_kernel_matches_bitsliced_ref():
+    """The stream-scaling kernel keeps every (parity, chunk) product apart:
+    out[:, j] is the bit-sliced oracle applied to chunk j alone."""
     from repro.core import gf256
 
     k, m, w = 3, 2, 32
@@ -62,9 +64,11 @@ def test_bitsliced_kernel_matches_bitsliced_ref():
     parity = gf256.cauchy_parity_matrix(k, m)
     bitmat = jnp.asarray(gf256.parity_bitmatrix(parity), jnp.uint32)
     planes = jnp.asarray(rng.integers(0, 2**32, (k, 8, w), dtype=np.uint32))
-    got = gf_matmul_bitsliced(bitmat, planes, m=m, k=k, block_w=8)
-    want = ref.gf_matmul_bitsliced_ref(bitmat, planes)
-    assert np.array_equal(np.asarray(got), np.asarray(want))
+    got = np.asarray(gf_scale_bitsliced(bitmat, planes, m=m, k=k, block_w=8,
+                                        interpret=ops._interpret()))
+    for j in range(k):
+        want = ref.gf_matmul_bitsliced_ref(bitmat[:, j:j + 1], planes[j:j + 1])
+        assert np.array_equal(got[:, j], np.asarray(want))
 
 
 @pytest.mark.parametrize("s", [1, 3])
@@ -78,7 +82,8 @@ def test_bitsliced_batched_kernel_matches_ref(s):
     parity = gf256.cauchy_parity_matrix(k, m)
     bitmat = jnp.asarray(gf256.parity_bitmatrix(parity), jnp.uint32)
     planes = jnp.asarray(rng.integers(0, 2**32, (s, k, 8, w), dtype=np.uint32))
-    got = gf_matmul_bitsliced_batched(bitmat, planes, m=m, k=k, block_w=8)
+    got = gf_matmul_bitsliced_batched(bitmat, planes, m=m, k=k, block_w=8,
+                                      interpret=ops._interpret())
     want = np.stack([
         np.asarray(ref.gf_matmul_bitsliced_ref(bitmat, planes[i]))
         for i in range(s)
@@ -89,7 +94,8 @@ def test_bitsliced_batched_kernel_matches_ref(s):
 def test_xor_reduce_batched_kernel():
     rng = np.random.default_rng(2)
     x = rng.integers(0, 2**32, (3, 5, 16), dtype=np.uint32)
-    got = np.asarray(xor_reduce_batched(jnp.asarray(x), block_w=8))
+    got = np.asarray(xor_reduce_batched(jnp.asarray(x), block_w=8,
+                                        interpret=ops._interpret()))
     assert np.array_equal(got, np.bitwise_xor.reduce(x, axis=1))
 
 
@@ -138,7 +144,8 @@ def test_pallas_flash_attention_matches_reference(hkv, causal, bq, bk):
     q = jnp.asarray(rng.standard_normal((b, s, h, d)), jnp.float32)
     k = jnp.asarray(rng.standard_normal((b, s, hkv, d)), jnp.float32)
     v = jnp.asarray(rng.standard_normal((b, s, hkv, d)), jnp.float32)
-    got = flash_attention_fwd(q, k, v, causal=causal, bq=bq, bk=bk)
+    got = flash_attention_fwd(q, k, v, causal=causal, bq=bq, bk=bk,
+                              interpret=ops._interpret())
     # reference: the (independently validated) jnp blockwise path
     from repro.models.attention import blockwise_attention
 
@@ -155,7 +162,8 @@ def test_flash_attention_ragged_seq_padding():
     q = jnp.asarray(rng.standard_normal((1, 50, 6, 8)), jnp.float32)
     k = jnp.asarray(rng.standard_normal((1, 50, 2, 8)), jnp.float32)
     v = jnp.asarray(rng.standard_normal((1, 50, 2, 8)), jnp.float32)
-    got = flash_attention_fwd(q, k, v, causal=True, bq=16, bk=16)
+    got = flash_attention_fwd(q, k, v, causal=True, bq=16, bk=16,
+                              interpret=ops._interpret())
     want = blockwise_attention(q, k, v, True, 16, 0)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=3e-4, atol=3e-4)

@@ -18,7 +18,6 @@ from jax.sharding import PartitionSpec as P, NamedSharding
 from functools import partial
 from repro.core.replication import ring_broadcast, pbt_broadcast, replicate
 from repro.core.packets import ReplStrategy
-from repro.parallel.compat import shard_map
 
 mesh = jax.make_mesh((8,), ("r",))
 rng = np.random.default_rng(0)
@@ -28,7 +27,7 @@ x = jax.device_put(jnp.asarray(data), NamedSharding(mesh, P("r")))
 for fn in (ring_broadcast, pbt_broadcast):
     for nc in (1, 4, 16):
         body = partial(fn, axis_name="r", num_chunks=nc, axis_size=8)
-        out = np.asarray(jax.jit(shard_map(
+        out = np.asarray(jax.jit(jax.shard_map(
             body, mesh=mesh, in_specs=P("r"), out_specs=P("r")))(x))
         for i in range(8):
             assert np.array_equal(out[i], data[0]), (fn.__name__, nc, i)
@@ -118,7 +117,6 @@ import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P, NamedSharding
 from repro.parallel.collectives import (ring_all_gather, ring_reduce_scatter,
                                         ring_all_reduce, make_ring_collective)
-from repro.parallel.compat import shard_map
 mesh = jax.make_mesh((8,), ("r",))
 rng = np.random.default_rng(0)
 x = rng.standard_normal((16, 4)).astype(np.float32)
@@ -132,8 +130,9 @@ ar = make_ring_collective(ring_all_reduce, mesh, "r")(xr)
 assert np.allclose(np.asarray(ar), 8 * x)
 vs = jax.device_put(jnp.asarray(rng.standard_normal((64, 3)).astype(np.float32)),
                     NamedSharding(mesh, P("r")))
-out = jax.jit(shard_map(lambda v: ring_all_reduce(v, "r", 8), mesh=mesh,
-                        in_specs=P("r"), out_specs=P("r"), check_vma=False))(vs)
+out = jax.jit(jax.shard_map(lambda v: ring_all_reduce(v, "r", 8), mesh=mesh,
+                            in_specs=P("r"), out_specs=P("r"),
+                            check_vma=False))(vs)
 blocks = np.asarray(vs).reshape(8, 8, 3)
 want = blocks.sum(axis=0)
 got = np.asarray(out).reshape(8, 8, 3)

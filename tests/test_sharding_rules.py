@@ -6,13 +6,13 @@ hide divisibility behavior — we construct a fake Mesh over the single CPU
 device reshaped logically via jax.sharding.AbstractMesh.
 """
 
+from jax.sharding import AbstractMesh
 from jax.sharding import PartitionSpec as P
 
 from repro.parallel import sharding as sh
-from repro.parallel.compat import abstract_mesh
 
-MESH = abstract_mesh((16, 16), ("data", "model"))
-MESH3 = abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+MESH3 = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 class _Leaf:
