@@ -1,0 +1,6 @@
+"""User bytes whose every shard was acknowledged, per second of window."""
+from chipbench.reduce import rate_MBps
+
+
+def read(run):
+    return rate_MBps(run.ops, run.elapsed_s)
