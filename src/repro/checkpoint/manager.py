@@ -25,6 +25,7 @@ from repro.core.auth import sponge_mac
 from repro.core.packets import ReplStrategy, Resiliency
 from repro.policy.functional import write_plan
 from repro.policy.spec import PolicySpec, RS, SpongeAuth, Tree
+from repro.trace import wall
 
 
 @dataclasses.dataclass(frozen=True)
@@ -106,7 +107,14 @@ class CheckpointManager:
         self._manifests: dict[int, dict] = {}
         self._pending: threading.Thread | None = None
         self._lock = threading.Lock()
+        #: per completed save, seconds from ``save()``'s entry (snapshot
+        #: included) to its manifest, less the wait for the previous
+        #: save's writer: the ``ckpt.save`` span's time less its
+        #: ``ckpt.wait`` child's
         self.save_seconds: list[float] = []
+        #: saves that raised, in the snapshot or on the writer thread
+        #: (their ``ckpt.save`` span is marked failed)
+        self.failed_saves = 0
 
     # -- save -------------------------------------------------------------------
 
@@ -114,63 +122,98 @@ class CheckpointManager:
         """Snapshot on the caller thread, write on a background thread."""
         import jax
 
-        flat, treedef = jax.tree_util.tree_flatten_with_path(tree)
-        # materialize to host now so training can mutate its arrays
-        snap = [(self._path_str(p), np.asarray(leaf)) for p, leaf in flat]
-        self.wait()
+        t0 = time.perf_counter_ns()
+        root = wall.begin("ckpt.save", "entry", t0)
+        parent = wall.parent_of(root)
+        try:
+            flat, treedef = jax.tree_util.tree_flatten_with_path(tree)
+            # materialize to host now so training can mutate its arrays
+            with wall.span("ckpt.snapshot", "entry", parent):
+                snap = [(self._path_str(p), np.asarray(leaf))
+                        for p, leaf in flat]
+            waited = self._wait_previous(parent)
+        except BaseException:
+            self._failed(root)
+            raise
 
         def worker():
-            t0 = time.time()
-            pol = self.policy
-            bulk_ec = (pol.resiliency == Resiliency.ERASURE_CODING
-                       and pol.encode == "client")
-            manifest = {"step": step, "leaves": [], "policy": {
-                "resiliency": int(pol.resiliency),
-                "k": pol.k, "m": pol.m, "encode": pol.encode,
-            }}
-            for path, arr in snap:
-                raw, meta = _leaf_to_bytes(arr)
-                blobs = [
-                    raw[off : off + pol.stripe_bytes]
-                    for off in range(0, max(len(raw), 1), pol.stripe_bytes)
-                ]
-                if bulk_ec:
-                    # one batched RSCode.encode_stripes per chunk-length
-                    # group across all stripes of this leaf
-                    layouts = self.cluster.write_object_bulk(
-                        blobs, k=pol.k, m=pol.m
-                    )
-                else:
-                    layouts = [
-                        self.cluster.write_object(
-                            blob,
-                            resiliency=pol.resiliency,
-                            k=pol.k,
-                            m=pol.m,
-                            strategy=pol.strategy,
-                        )
-                        for blob in blobs
-                    ]
-                stripes = [
-                    {"oid": layout.object_id, "size": len(blob)}
-                    for layout, blob in zip(layouts, blobs)
-                ]
-                mac = sponge_mac(
-                    np.frombuffer(raw[:64].ljust(64, b"\0"), np.uint32),
-                    self.cluster.meta.authority.key,
-                )
-                manifest["leaves"].append(
-                    {"path": path, "meta": meta, "stripes": stripes,
-                     "mac": [int(mac[0]), int(mac[1])], "bytes": len(raw)}
-                )
+            try:
+                manifest = self._write_leaves(step, snap, parent)
+            except BaseException:
+                self._failed(root)
+                raise
             with self._lock:
                 self._manifests[step] = manifest
-            self.save_seconds.append(time.time() - t0)
+            t1 = time.perf_counter_ns()
+            wall.end(root, t1=t1)
+            self.save_seconds.append((t1 - t0 - waited) / 1e9)
 
         self._pending = threading.Thread(target=worker, daemon=True)
         self._pending.start()
         if blocking:
             self.wait()
+
+    def _wait_previous(self, parent) -> int:
+        """Wait for the previous save's writer, under a ``ckpt.wait``
+        span; returns the nanoseconds waited."""
+        w0 = time.perf_counter_ns()
+        live = wall.begin("ckpt.wait", "entry", w0, parent)
+        self.wait()
+        w1 = time.perf_counter_ns()
+        wall.end(live, t1=w1)
+        return w1 - w0
+
+    def _failed(self, root) -> None:
+        self.failed_saves += 1
+        wall.end(root, failed=True)
+
+    def _write_leaves(self, step: int, snap: list, parent) -> dict:
+        """Write every leaf of a snapshot; returns the save's manifest."""
+        pol = self.policy
+        bulk_ec = (pol.resiliency == Resiliency.ERASURE_CODING
+                   and pol.encode == "client")
+        manifest = {"step": step, "leaves": [], "policy": {
+            "resiliency": int(pol.resiliency),
+            "k": pol.k, "m": pol.m, "encode": pol.encode,
+        }}
+        for path, arr in snap:
+            with wall.span("ckpt.leaf", "entry", parent):
+                manifest["leaves"].append(self._write_leaf(path, arr, bulk_ec))
+        return manifest
+
+    def _write_leaf(self, path: str, arr: np.ndarray, bulk_ec: bool) -> dict:
+        pol = self.policy
+        raw, meta = _leaf_to_bytes(arr)
+        blobs = [
+            raw[off : off + pol.stripe_bytes]
+            for off in range(0, max(len(raw), 1), pol.stripe_bytes)
+        ]
+        if bulk_ec:
+            # one batched RSCode.encode_stripes per chunk-length
+            # group across all stripes of this leaf
+            layouts = self.cluster.write_object_bulk(blobs, k=pol.k, m=pol.m)
+        else:
+            layouts = [
+                self.cluster.write_object(
+                    blob,
+                    resiliency=pol.resiliency,
+                    k=pol.k,
+                    m=pol.m,
+                    strategy=pol.strategy,
+                )
+                for blob in blobs
+            ]
+        stripes = [
+            {"oid": layout.object_id, "size": len(blob)}
+            for layout, blob in zip(layouts, blobs)
+        ]
+        with wall.span("ckpt.mac", "entry"):
+            mac = sponge_mac(
+                np.frombuffer(raw[:64].ljust(64, b"\0"), np.uint32),
+                self.cluster.meta.authority.key,
+            )
+        return {"path": path, "meta": meta, "stripes": stripes,
+                "mac": [int(mac[0]), int(mac[1])], "bytes": len(raw)}
 
     def wait(self) -> None:
         if self._pending is not None and self._pending.is_alive():

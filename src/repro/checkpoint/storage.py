@@ -29,6 +29,7 @@ from repro.core.handlers import DFSClient, DFSNode, Router
 from repro.core.packets import OpType, ReplicaCoord, ReplStrategy, Resiliency
 from repro.namenode.placement import PlacementPolicy, RoundRobinPlacement
 from repro.policy.functional import write_plan
+from repro.trace import wall
 
 
 @dataclasses.dataclass
@@ -279,6 +280,11 @@ class StorageCluster:
         data-plane default, the kernels on a TPU), then every data/parity
         shard is written as an authenticated plain write through the
         policy engine."""
+        with wall.span("cluster.write", "entry"):
+            return self._write_object_bulk(blobs, k, m, backend)
+
+    def _write_object_bulk(self, blobs, k: int, m: int,
+                           backend: str | None) -> list[ObjectLayout]:
         from repro.core.erasure import RSCode, split_stripe
 
         arrs = [
@@ -399,6 +405,11 @@ class StorageCluster:
         the bytes are returned.  Replicated objects fail over to the
         first surviving replica.
         """
+        with wall.span("cluster.read", "entry"):
+            return self._read_objects(layouts, verify, backend)
+
+    def _read_objects(self, layouts: list[ObjectLayout], verify: bool,
+                      backend: str | None) -> list[bytes]:
         from repro.core.erasure import RSCode
 
         out: list[bytes | None] = [None] * len(layouts)
@@ -578,27 +589,28 @@ class StorageCluster:
         foreground reads treat its shards as missing (degraded
         reconstruction returns correct bytes) and placement avoids it —
         only the final lock acquisition marks it live again."""
-        in_place = replacement is None or replacement == node_id
-        if not in_place and replacement in self.failed:
-            raise ValueError(f"replacement node {replacement} is failed")
-        with self._io_lock:
-            stats, tasks = self._repair_collect(node_id, replacement,
-                                                in_place)
-        touched: set[int] = set()
-        for layout, idx, shard in tasks:
-            if pacer is not None:
-                stats["paced_wait_s"] += pacer.throttle(int(shard.size))
+        with wall.span("cluster.repair", "entry"):
+            in_place = replacement is None or replacement == node_id
+            if not in_place and replacement in self.failed:
+                raise ValueError(f"replacement node {replacement} is failed")
             with self._io_lock:
-                self._write_rebuilt(layout, idx, shard, node_id,
-                                    replacement, stats)
-            touched.add(id(layout))
-        with self._io_lock:
-            if in_place:
-                # every shard is back: the node may serve reads again
-                self.failed.discard(node_id)
-            stats["objects"] = len(touched)
-            self.repair_stats = stats
-        return stats
+                stats, tasks = self._repair_collect(node_id, replacement,
+                                                    in_place)
+            touched: set[int] = set()
+            for layout, idx, shard in tasks:
+                if pacer is not None:
+                    stats["paced_wait_s"] += pacer.throttle(int(shard.size))
+                with self._io_lock:
+                    self._write_rebuilt(layout, idx, shard, node_id,
+                                        replacement, stats)
+                touched.add(id(layout))
+            with self._io_lock:
+                if in_place:
+                    # every shard is back: the node may serve reads again
+                    self.failed.discard(node_id)
+                stats["objects"] = len(touched)
+                self.repair_stats = stats
+            return stats
 
     def _repair_collect(
         self, node_id: int, replacement: int | None, in_place: bool

@@ -27,6 +27,8 @@ import struct
 
 import numpy as np
 
+from repro.trace import wall
+
 MAC_ROUNDS = 8
 _MASK32 = 0xFFFFFFFF
 
@@ -189,6 +191,8 @@ class CapabilityAuthority:
         self.key = np.asarray(key, dtype=np.uint32)
         if self.key.shape != (4,):
             raise ValueError("key must be 4 uint32 words")
+        #: capability checks made by :meth:`verify`
+        self.verifications = 0
 
     def issue(
         self,
@@ -215,7 +219,9 @@ class CapabilityAuthority:
         client_id: int | None = None,
     ) -> bool:
         """Full header-handler check: MAC, expiry, rights, extent, identity."""
-        tag = sponge_mac(cap.words(), self.key)
+        self.verifications += 1
+        with wall.span("auth.verify", "packet"):
+            tag = sponge_mac(cap.words(), self.key)
         if (int(tag[0]), int(tag[1])) != cap.tag:
             return False
         if now > cap.expiry:

@@ -22,6 +22,7 @@ from typing import Sequence
 import numpy as np
 
 from repro.core import gf256
+from repro.trace import wall
 
 
 @dataclasses.dataclass(frozen=True)
@@ -95,7 +96,10 @@ class RSCode:
         backend="jax" is one fused kernel dispatch for the whole batch
         (kernels/ops.py); backend="numpy" is the vectorized host LUT path.
         """
-        backend = _backend(backend)
+        with wall.span("rs.encode", "coding"):
+            return self._encode_stripes(data, _backend(backend))
+
+    def _encode_stripes(self, data: np.ndarray, backend: str) -> np.ndarray:
         data = np.asarray(data, dtype=np.uint8)
         if data.ndim != 3 or data.shape[1] != self.k:
             raise ValueError(f"expected (S, {self.k}, L) stripes, got {data.shape}")
@@ -109,7 +113,7 @@ class RSCode:
         if backend == "jax":
             from repro.kernels import ops
 
-            return np.asarray(
+            return ops.to_host(
                 ops.rs_encode_stripes(data, self.k, self.m, kind=self.kind)
             )
         raise ValueError(f"unknown backend {backend!r}")
@@ -156,6 +160,10 @@ class RSCode:
         stripe — the common whole-node-failure case).  One fused kernel
         dispatch recovers all S stripes.
         """
+        with wall.span("rs.decode", "coding"):
+            return self._decode_stripes(shards, backend)
+
+    def _decode_stripes(self, shards, backend: str | None) -> np.ndarray:
         if len(shards) != self.n:
             raise ValueError(f"expected {self.n} shard slots, got {len(shards)}")
         present = [i for i, s in enumerate(shards) if s is not None]
@@ -176,7 +184,7 @@ class RSCode:
         if _backend(backend) == "jax":
             from repro.kernels import ops
 
-            return np.asarray(ops.gf_matmul_bytes_batched(inv, stacked))
+            return ops.to_host(ops.gf_matmul_bytes_batched(inv, stacked))
         s, _, length = stacked.shape
         flat = stacked.transpose(1, 0, 2).reshape(self.k, s * length)
         out = gf256.gf_matmul(inv, flat)

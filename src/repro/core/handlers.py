@@ -52,6 +52,7 @@ from repro.core.state import RequestEntry, RequestTable
 from repro.membership.detector import MembershipConfig
 from repro.membership.retry import RetryExhausted, RetryPolicy
 from repro.membership.view import ViewManager
+from repro.trace import wall
 
 # NB: repro.policy.functional is imported lazily (function scope) — the
 # policy package imports repro.core.packets, so a module-level import here
@@ -77,13 +78,10 @@ class StorageTarget:
         return self.mem[addr : addr + size].copy()
 
 
-@dataclasses.dataclass
-class Event:
-    """Handler -> host-software event queue entry (section III-C)."""
-
-    kind: str
-    greq_id: int
-    detail: str = ""
+#: What a node's handlers report to host software (section III-C), each
+#: counted per node in :attr:`DFSNode.counts`.
+NODE_COUNT_KINDS = ("deny_full", "ec_cpu_fallback", "parity_done",
+                    "write_done", "nack", "read_done", "cleanup")
 
 
 class Router:
@@ -99,7 +97,9 @@ class Router:
         self.client_acks: dict[int, list[Packet]] = defaultdict(list)
         self._queue: list[tuple[int, Packet]] = []
         self._draining = False
+        #: packets handed to a node / to a client's inbox
         self.packets_delivered = 0
+        self.packets_to_clients = 0
         self.packets_dropped = 0
         self.failed: set[int] = set()
         self.loss: dict[int, float] = {}
@@ -145,6 +145,7 @@ class Router:
             self._drain()
 
     def send_to_client(self, client_id: int, pkt: Packet) -> None:
+        self.packets_to_clients += 1
         self.client_acks[client_id].append(pkt)
 
     def _drain(self) -> None:
@@ -197,7 +198,8 @@ class DFSNode:
         self.req_table = RequestTable(req_table_capacity)
         self.mtu = mtu
         self.now_fn = now_fn
-        self.events: list[Event] = []
+        #: kind (:data:`NODE_COUNT_KINDS`) -> events the handlers reported
+        self.counts = dict.fromkeys(NODE_COUNT_KINDS, 0)
         self._reqs: dict[int, _ReqState] = {}
         self._parents: dict[int, int | None] = {}
         # EC aggregation state: greq -> (pool, seq->done-count bookkeeping)
@@ -223,7 +225,7 @@ class DFSNode:
         )
         if accept and not entry_ok:
             accept = False  # table full: deny, client retries (section III-B2)
-            self.events.append(Event("deny_full", dfs.greq_id))
+            self.counts["deny_full"] += 1
         from repro.policy.functional import payload_stages
 
         self._reqs[dfs.greq_id] = _ReqState(
@@ -373,7 +375,7 @@ class DFSNode:
         if idx is None:
             idx = self._acc_pool.allocate()
             if idx is None:
-                self.events.append(Event("ec_cpu_fallback", pkt.greq_id))
+                self.counts["ec_cpu_fallback"] += 1
                 return
             agg["table"][key] = idx
         count = self._acc_pool.xor_into(idx, pkt.payload)
@@ -399,7 +401,7 @@ class DFSNode:
             self.router.send_to_client(
                 agg["client_id"], _control_packet(stripe, OpType.WRITE_ACK)
             )
-            self.events.append(Event("parity_done", stripe))
+            self.counts["parity_done"] += 1
 
     # -- Listing 1: completion handler ----------------------------------------
 
@@ -423,7 +425,7 @@ class DFSNode:
             self.router.send_to_client(st.client_id, ack)
         else:
             self.router.send(st.parent, ack)
-        self.events.append(Event("write_done", greq_id))
+        self.counts["write_done"] += 1
 
     def _on_child_ack(self, greq_id: int) -> None:
         st = self._reqs.get(greq_id)
@@ -434,7 +436,7 @@ class DFSNode:
 
     def _nack(self, greq_id: int, client_id: int) -> None:
         self.router.send_to_client(client_id, _control_packet(greq_id, OpType.NACK))
-        self.events.append(Event("nack", greq_id))
+        self.counts["nack"] += 1
 
     # -- read path (first read-policy: request up, data streamed back) -------
 
@@ -481,7 +483,7 @@ class DFSNode:
             idx += 1
             if is_last:
                 break
-        self.events.append(Event("read_done", dfs.greq_id))
+        self.counts["read_done"] += 1
 
     # -- dispatch -------------------------------------------------------------
 
@@ -513,7 +515,7 @@ class DFSNode:
                     for idx in agg["table"].values():
                         self._acc_pool.release(idx)
                 del self._reqs[g]
-                self.events.append(Event("cleanup", g))
+                self.counts["cleanup"] += 1
         return self.req_table.cleanup_stale(alive)
 
 
@@ -1257,6 +1259,12 @@ class DFSClient:
     ) -> list[int]:
         """Issue a write; returns the greq ids used (1 for raw/replicated,
         k for erasure-coded stripes).  Acks land in router.client_acks."""
+        with wall.span("dfs.write", "packet"):
+            return self._write(capability, data, targets, resiliency,
+                               strategy, ec_m, parity_targets)
+
+    def _write(self, capability, data, targets, resiliency, strategy, ec_m,
+               parity_targets) -> list[int]:
         data = np.asarray(data, dtype=np.uint8).ravel()
         if resiliency in (Resiliency.NONE, Resiliency.REPLICATION):
             greq = self._greq()
@@ -1269,7 +1277,9 @@ class DFSClient:
                 virtual_rank=0,
                 replicas=tuple(targets) if resiliency == Resiliency.REPLICATION else (),
             )
-            for pkt in packetize_write(dfs, wrh, data, self.mtu):
+            with wall.span("dfs.frame", "packet"):
+                pkts = packetize_write(dfs, wrh, data, self.mtu)
+            for pkt in pkts:
                 self.router.send(targets[0].node, pkt)
             return [greq]
         # Erasure coding: split into k chunks, one write per data node,
@@ -1281,23 +1291,24 @@ class DFSClient:
         stripe_id = self._greq() & 0xFFFFFFFF  # shared 32-bit stripe id
         greqs = [stripe_id]  # parity acks carry the stripe id
         pkt_streams = []
-        for j in range(k):
-            greq = self._greq()
-            greqs.append(greq)
-            dfs = DFSHeader(OpType.WRITE, greq, self.client_id, capability)
-            wrh = WriteRequestHeader(
-                addr=targets[j].addr,
-                size=int(chunks.shape[1]),
-                resiliency=Resiliency.ERASURE_CODING,
-                ec_k=k,
-                ec_m=ec_m,
-                ec_index=j,
-                replicas=tuple(parity_targets),
-                seq=stripe_id,
-            )
-            pkt_streams.append(
-                packetize_write(dfs, wrh, chunks[j], self.mtu)
-            )
+        with wall.span("dfs.frame", "packet"):
+            for j in range(k):
+                greq = self._greq()
+                greqs.append(greq)
+                dfs = DFSHeader(OpType.WRITE, greq, self.client_id, capability)
+                wrh = WriteRequestHeader(
+                    addr=targets[j].addr,
+                    size=int(chunks.shape[1]),
+                    resiliency=Resiliency.ERASURE_CODING,
+                    ec_k=k,
+                    ec_m=ec_m,
+                    ec_index=j,
+                    replicas=tuple(parity_targets),
+                    seq=stripe_id,
+                )
+                pkt_streams.append(
+                    packetize_write(dfs, wrh, chunks[j], self.mtu)
+                )
         # Interleave: seq 0 of every chunk, then seq 1, ... (Fig. 14).
         max_len = max(len(s) for s in pkt_streams)
         for i in range(max_len):
@@ -1346,6 +1357,10 @@ class DFSClient:
         """Authenticated read: READ request up, READ_RESP packets streamed
         back by the node's read pipeline.  Returns the bytes; raises
         :class:`IOError` on NACK or short data."""
+        with wall.span("dfs.read", "packet"):
+            return self._read(capability, coord, size)
+
+    def _read(self, capability, coord: ReplicaCoord, size: int) -> np.ndarray:
         greq = self._greq()
         dfs = DFSHeader(OpType.READ, greq, self.client_id, capability)
         rrh = ReadRequestHeader(addr=coord.addr, size=size)
@@ -1371,11 +1386,13 @@ class DFSClient:
             raise IOError(f"read {greq}: denied (NACK)")
         out = np.zeros(size, dtype=np.uint8)
         got = 0
-        for p in resps:
-            if p.ctrl != OpType.READ_RESP or p.greq_id != greq:
-                continue
-            out[p.payload_offset : p.payload_offset + p.payload_size] = p.payload
-            got += p.payload_size
+        with wall.span("dfs.assemble", "packet"):
+            for p in resps:
+                if p.ctrl != OpType.READ_RESP or p.greq_id != greq:
+                    continue
+                out[p.payload_offset : p.payload_offset + p.payload_size] = (
+                    p.payload)
+                got += p.payload_size
         if got != size:
             raise IOError(f"read {greq}: got {got}/{size} bytes")
         return out

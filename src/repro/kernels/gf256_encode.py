@@ -113,6 +113,7 @@ def gf_matmul_bitsliced_batched(
         out_specs=pl.BlockSpec((1, m * 8, block_w), lambda si, wi: (si, 0, wi)),
         out_shape=jax.ShapeDtypeStruct((s, m * 8, w), jnp.uint32),
         interpret=interpret,
+        name="rs_gf_matmul",
     )(_masks(bitmat), planes)
     return out.reshape(s, m, 8, w)
 

@@ -24,6 +24,7 @@ from repro.kernels.gf256_encode import (
     gf_scale_bitsliced,
 )
 from repro.kernels.xor_reduce import xor_reduce_batched as _xor_reduce_batched
+from repro.trace import wall
 
 
 def _on_tpu() -> bool:
@@ -32,6 +33,24 @@ def _on_tpu() -> bool:
 
 def _interpret() -> bool:
     return not _on_tpu()
+
+
+#: The codec's transfers and dispatches since the process started, always
+#: on: read them as differences between two snapshots.  ``dispatches`` /
+#: ``stripes`` count :func:`gf_matmul_bytes_batched`'s fused dispatches
+#: and the stripes they carried, ``h2d_bytes`` the host stripe bytes it
+#: moved to the device, ``d2h_bytes`` what :func:`to_host` brought back.
+CODEC_COUNTS = dict.fromkeys(("dispatches", "stripes", "h2d_bytes",
+                              "d2h_bytes"), 0)
+
+
+def to_host(x: jax.Array) -> np.ndarray:
+    """The codec's result as a host array: waits for the device and
+    copies, counted in ``CODEC_COUNTS["d2h_bytes"]``."""
+    with wall.span("codec.d2h", "coding"):
+        out = np.asarray(x)
+    CODEC_COUNTS["d2h_bytes"] += out.nbytes
+    return out
 
 
 def dataplane_backend(backend: str | None = None) -> str:
@@ -91,13 +110,17 @@ def _pick_block_w(length: int, block_w: int | None) -> int:
 @functools.partial(jax.jit, static_argnames=("block_w", "interpret"))
 def _encode_planes_batched(bitmat, data_bytes, block_w, interpret):
     """Fused pipeline under one jit: bit-plane pack -> single batched Pallas
-    dispatch over the (stripe, word-block) grid -> unpack."""
-    planes = ref.pack_bitplanes(data_bytes)          # (S, k, 8, w)
+    dispatch over the (stripe, word-block) grid -> unpack.  The pack and
+    unpack ops carry the ``rs_pack`` / ``rs_unpack`` scopes in their op
+    names, the kernel is ``rs_gf_matmul``."""
+    with jax.named_scope("rs_pack"):
+        planes = ref.pack_bitplanes(data_bytes)      # (S, k, 8, w)
     m, k = bitmat.shape[0], bitmat.shape[1]
     out_planes = gf_matmul_bitsliced_batched(
         bitmat, planes, m=m, k=k, block_w=block_w, interpret=interpret
     )
-    return ref.unpack_bitplanes(out_planes)          # (S, m, L)
+    with jax.named_scope("rs_unpack"):
+        return ref.unpack_bitplanes(out_planes)      # (S, m, L)
 
 
 #: Payload bytes, (k + n) * L per stripe, that one fused dispatch may
@@ -152,11 +175,18 @@ def gf_matmul_bytes_batched(
     bitmat = _bitmat_device(coeffs_np.tobytes(), n, k)
     outs, lo = [], 0
     for size in _dispatch_sizes(s, (k + n) * length):
-        piece = jnp.asarray(data[lo:lo + size], dtype=jnp.uint8)
-        # Pad L so the packed word count divides the kernel block.
-        piece, _ = _pad_to(piece, 32 * bw, axis=2)
-        out = _encode_planes_batched(bitmat, piece, bw, _interpret())
-        outs.append(out[:, :, :length])
+        piece = data[lo:lo + size]
+        if not isinstance(piece, jax.Array):
+            CODEC_COUNTS["h2d_bytes"] += piece.nbytes
+        with wall.span("codec.h2d", "coding"):
+            piece = jnp.asarray(piece, dtype=jnp.uint8)
+        with wall.span("codec.launch", "coding"):
+            # Pad L so the packed word count divides the kernel block.
+            piece, _ = _pad_to(piece, 32 * bw, axis=2)
+            out = _encode_planes_batched(bitmat, piece, bw, _interpret())
+            outs.append(out[:, :, :length])
+        CODEC_COUNTS["dispatches"] += 1
+        CODEC_COUNTS["stripes"] += size
         lo += size
     return outs[0] if len(outs) == 1 else jnp.concatenate(outs)
 

@@ -1,6 +1,7 @@
 """``repro.trace`` — sampled request tracing, unified counters, exporters.
 
-The observability layer for the timed plane (ISSUE 10):
+The observability layer for the timed plane, and with :mod:`.wall` for
+the served data plane:
 
 * :class:`Tracer` / :class:`Span` — head-sampled, zero-cost-when-off
   span recording (install via ``env.sim.tracer`` or
@@ -11,16 +12,19 @@ The observability layer for the timed plane (ISSUE 10):
   ``chrome://tracing`` export
 * :mod:`repro.trace.attr` — per-request / per-policy latency attribution
   into wire / hpu_queue / hpu_exec / pcie / host_cpu / client buckets
+* :mod:`repro.trace.wall` — wall-clock spans of the served data plane
+  (``span(name, layer)``, on while a ``Tracer.wall()`` is installed) and
+  :func:`dataplane_registry` over its always-on counters
 """
 
 from .tracer import BUCKETS, Span, Tracer
-from .counters import CounterRegistry, registry_for
+from .counters import CounterRegistry, dataplane_registry, registry_for
 from .perfetto import to_chrome_trace, write_chrome_trace
-from . import attr
+from . import attr, wall
 
 __all__ = [
     "BUCKETS", "Span", "Tracer",
-    "CounterRegistry", "registry_for",
+    "CounterRegistry", "dataplane_registry", "registry_for",
     "to_chrome_trace", "write_chrome_trace",
-    "attr",
+    "attr", "wall",
 ]

@@ -17,6 +17,10 @@ tracks handler time and HPU-pool occupancy, every
 * ``snapshot()`` — ``{name: value}`` at this instant
 * ``diff(a, b)`` — per-name deltas between two snapshots
 
+``dataplane_registry(cluster)`` does the same for the served data plane
+(a :class:`~repro.checkpoint.storage.StorageCluster`): its always-on
+packet, capability, codec and per-node counters.
+
 ``registry_for(env, ...)`` wires a registry over an
 :class:`~repro.sim.protocols.Env` (network + PsPIN + serial resources +
 engine), aggregating per-node resources into per-class totals so the
@@ -153,5 +157,50 @@ def registry_for(env, metrics=None, telemetry=None) -> CounterRegistry:
             "windows": len(telemetry.windows),
             "evicted": telemetry.evicted,
             "lost_packets": sum(w.lost_packets for w in telemetry.windows),
+        })
+    return reg
+
+
+def dataplane_registry(cluster, manager=None) -> CounterRegistry:
+    """The counters of one storage cluster's data plane (and of the
+    :class:`~repro.checkpoint.manager.CheckpointManager` saving into it):
+
+    * ``packets.to_nodes`` / ``.to_clients`` / ``.dropped`` -- the
+      router's deliveries to storage nodes, to client inboxes (acks,
+      NACKs, read responses) and its drops;
+    * ``auth.verifications`` -- capability checks by the header handlers;
+    * ``codec.dispatches`` / ``.stripes`` / ``.h2d_bytes`` /
+      ``.d2h_bytes`` -- the process's fused codec dispatches and transfers
+      (``repro.kernels.ops.CODEC_COUNTS``, shared by every cluster);
+    * ``node.<kind>`` -- the nodes' handler events by kind, summed;
+    * ``ckpt.saves`` / ``.failed_saves`` with ``manager``.
+
+    Every counter only grows.  A window that moves to a new cluster part
+    way reads each cluster's registry at the ends of its own part."""
+    from repro.kernels.ops import CODEC_COUNTS
+
+    router = cluster.router
+    authority = cluster.meta.authority
+    reg = CounterRegistry()
+    reg.register_group("packets", lambda: {
+        "to_nodes": router.packets_delivered,
+        "to_clients": router.packets_to_clients,
+        "dropped": router.packets_dropped,
+    })
+    reg.register("auth.verifications", lambda: authority.verifications)
+    reg.register_group("codec", lambda: dict(CODEC_COUNTS))
+
+    def node_group():
+        out: dict[str, int] = {}
+        for node in cluster.nodes:
+            for kind, n in node.counts.items():
+                out[kind] = out.get(kind, 0) + n
+        return out
+
+    reg.register_group("node", node_group)
+    if manager is not None:
+        reg.register_group("ckpt", lambda: {
+            "saves": len(manager.save_seconds),
+            "failed_saves": manager.failed_saves,
         })
     return reg

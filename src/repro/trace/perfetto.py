@@ -10,7 +10,9 @@ https://ui.perfetto.dev open directly.  Mapping:
 * **thread** (tid): the full resource name; queue-wait spans live on
   their own ``... (queue)`` track so service tracks stay non-overlapping.
 * ``ts`` / ``dur`` are microseconds (the format's unit); sim times are
-  nanoseconds, so everything is divided by 1e3.
+  nanoseconds, so everything is divided by 1e3.  A wall-clock tracer's
+  spans (``perf_counter_ns``) land on one track per thread and name
+  their parent span in ``args``.
 
 The output is deterministic — spans sorted by ``(ts, tid, name)``,
 track ids assigned in sorted-name order — so golden-file tests can
@@ -57,6 +59,8 @@ def to_chrome_trace(tracer) -> dict:
     for s in spans:
         p = _proc(s, tracer.policy_name)
         args = {"rid": s.rid, "policy": tracer.policy_name(s.pid)}
+        if s.parent is not None:
+            args["parent"] = s.parent.name
         if s.args:
             args.update(s.args)
         events.append({
