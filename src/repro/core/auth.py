@@ -7,12 +7,13 @@ nodes validate the capability in the header handler before accepting the
 rest of the request's packets.
 
 The MAC is a keyed ARX sponge over 32-bit words chosen so the exact same
-computation runs (a) on the host control plane (numpy), and (b) as a
-vectorized bulk verifier inside jitted JAX data paths (e.g. validating a
-batch of restore requests in one fused op).  It is *not* a standardized
-algorithm; it plays the role of the paper's 200-cycle header-handler check
-and of [32]-style capability signatures.  Swapping in HMAC-SHA256 on the
-host path is a one-line change (`Capability.mac_backend`).
+computation runs (a) on the host, on Python ints for one ticket and on
+numpy vectors for a batch, and (b) as a vectorized bulk verifier inside
+jitted JAX data paths (e.g. validating a batch of restore requests in one
+fused op).  It is *not* a standardized algorithm; it plays the role of
+the paper's 200-cycle header-handler check and of [32]-style capability
+signatures.  Swapping in HMAC-SHA256 on the host path is a one-line
+change (`Capability.mac_backend`).
 
 Rights are a bitmap; extents are byte ranges of an object id.  The verifier
 checks signature, expiry, rights superset, and extent containment — the
@@ -23,6 +24,7 @@ from __future__ import annotations
 
 import dataclasses
 import enum
+import functools
 import struct
 
 import numpy as np
@@ -48,68 +50,90 @@ def _rotl(x, r, xp):
     return (left | right) & xp.uint32(_MASK32)
 
 
+def _int_rounds(n, v0, v1, v2, v3):
+    """``n`` ARX rounds of :func:`sponge_mac` on Python ints."""
+    m = _MASK32
+    for _ in range(n):
+        v0 = (v0 + v1) & m
+        v1 = ((v1 << 5 | v1 >> 27) & m) ^ v0
+        v0 = (v0 << 16 | v0 >> 16) & m
+        v2 = (v2 + v3) & m
+        v3 = ((v3 << 8 | v3 >> 24) & m) ^ v2
+        v0 = (v0 + v3) & m
+        v3 = ((v3 << 13 | v3 >> 19) & m) ^ v0
+        v2 = (v2 + v1) & m
+        v1 = ((v1 << 7 | v1 >> 25) & m) ^ v2
+        v2 = (v2 << 16 | v2 >> 16) & m
+    return v0, v1, v2, v3
+
+
+def _mac_ints(words, key):
+    """:func:`sponge_mac` of one word vector, on Python ints.
+
+    ``words`` and ``key`` are sequences of ints below 2**32; returns the
+    tag as a pair of ints.  A numpy scalar op costs ~1 us and a Python int
+    op tens of ns, so the host's single-ticket checks run here.
+    """
+    v0 = key[0] ^ 0x736F6D65
+    v1 = key[1] ^ 0x646F7261
+    v2 = key[2] ^ 0x6C796765
+    v3 = key[3] ^ 0x74656462
+    for w in words:
+        v0, v1, v2, v3 = _int_rounds(2, v0, v1, v2, v3 ^ w)
+        v0 ^= w
+    v0, v1, v2, v3 = _int_rounds(MAC_ROUNDS, v0, v1, v2 ^ 0xFF, v3)
+    return v0 ^ v1, v2 ^ v3
+
+
 def sponge_mac(words, key_words, xp=np):
     """Keyed ARX sponge MAC over uint32 words -> (2,) uint32 tag.
 
     ``words``: (..., W) uint32; ``key_words``: (4,) uint32.  Works with
     ``xp=np`` (host) and ``xp=jnp`` (bulk JAX verifier); both produce
-    identical tags (property-tested).
+    identical tags (property-tested).  One host vector, shape (W,), runs
+    on Python ints (:func:`_mac_ints`); a batch, shape (N, W), runs the
+    vectorised numpy rounds.
     """
-    if xp is np:
-        # uint32 wraparound is intended; silence numpy 2.x scalar-overflow
-        # warnings for the whole computation.
-        import contextlib
+    words = xp.asarray(words, dtype=xp.uint32)
+    key = xp.asarray(key_words, dtype=xp.uint32)
+    if xp is np and words.ndim == 1:
+        return np.array(_mac_ints(words.tolist(), key.tolist()), np.uint32)
 
-        ctx = np.errstate(over="ignore")
-        words = np.asarray(words, dtype=np.uint32)
-        key = np.asarray(key_words, dtype=np.uint32)
-    else:
-        import contextlib
+    batch = words.shape[:-1]
+    ones = xp.ones(batch + (1,), dtype=xp.uint32) if batch else None
 
-        ctx = contextlib.nullcontext()
-        words = xp.asarray(words, dtype=xp.uint32)
-        key = xp.asarray(key_words, dtype=xp.uint32)
+    def bcast(k):
+        return k * ones[..., 0] if ones is not None else k
 
-    with ctx:
-        batch = words.shape[:-1]
-        ones = xp.ones(batch + (1,), dtype=xp.uint32) if batch else None
+    v0 = bcast(key[0] ^ xp.uint32(0x736F6D65))
+    v1 = bcast(key[1] ^ xp.uint32(0x646F7261))
+    v2 = bcast(key[2] ^ xp.uint32(0x6C796765))
+    v3 = bcast(key[3] ^ xp.uint32(0x74656462))
 
-        def bcast(k):
-            return k * ones[..., 0] if ones is not None else k
+    def round_fn(v0, v1, v2, v3):
+        v0 = (v0 + v1) & xp.uint32(_MASK32)
+        v1 = _rotl(v1, 5, xp) ^ v0
+        v0 = _rotl(v0, 16, xp)
+        v2 = (v2 + v3) & xp.uint32(_MASK32)
+        v3 = _rotl(v3, 8, xp) ^ v2
+        v0 = (v0 + v3) & xp.uint32(_MASK32)
+        v3 = _rotl(v3, 13, xp) ^ v0
+        v2 = (v2 + v1) & xp.uint32(_MASK32)
+        v1 = _rotl(v1, 7, xp) ^ v2
+        v2 = _rotl(v2, 16, xp)
+        return v0, v1, v2, v3
 
-        v0 = bcast(key[0] ^ xp.uint32(0x736F6D65))
-        v1 = bcast(key[1] ^ xp.uint32(0x646F7261))
-        v2 = bcast(key[2] ^ xp.uint32(0x6C796765))
-        v3 = bcast(key[3] ^ xp.uint32(0x74656462))
-
-        def round_fn(v0, v1, v2, v3):
-            v0 = (v0 + v1) & xp.uint32(_MASK32)
-            v1 = _rotl(v1, 5, xp) ^ v0
-            v0 = _rotl(v0, 16, xp)
-            v2 = (v2 + v3) & xp.uint32(_MASK32)
-            v3 = _rotl(v3, 8, xp) ^ v2
-            v0 = (v0 + v3) & xp.uint32(_MASK32)
-            v3 = _rotl(v3, 13, xp) ^ v0
-            v2 = (v2 + v1) & xp.uint32(_MASK32)
-            v1 = _rotl(v1, 7, xp) ^ v2
-            v2 = _rotl(v2, 16, xp)
-            return v0, v1, v2, v3
-
-        nwords = words.shape[-1]
-        for i in range(nwords):
-            w = words[..., i]
-            v3 = v3 ^ w
-            for _ in range(2):
-                v0, v1, v2, v3 = round_fn(v0, v1, v2, v3)
-            v0 = v0 ^ w
-        v2 = v2 ^ xp.uint32(0xFF)
-        for _ in range(MAC_ROUNDS):
+    nwords = words.shape[-1]
+    for i in range(nwords):
+        w = words[..., i]
+        v3 = v3 ^ w
+        for _ in range(2):
             v0, v1, v2, v3 = round_fn(v0, v1, v2, v3)
-        t0 = v0 ^ v1
-        t1 = v2 ^ v3
-        if xp is np:
-            return np.stack([t0, t1], axis=-1).astype(np.uint32)
-        return xp.stack([t0, t1], axis=-1)
+        v0 = v0 ^ w
+    v2 = v2 ^ xp.uint32(0xFF)
+    for _ in range(MAC_ROUNDS):
+        v0, v1, v2, v3 = round_fn(v0, v1, v2, v3)
+    return xp.stack([v0 ^ v1, v2 ^ v3], axis=-1)
 
 
 # Capability wire layout (little-endian uint32 words):
@@ -135,27 +159,29 @@ class Capability:
     nonce: int = 0
     tag: tuple[int, int] = (0, 0)
 
-    def words(self) -> np.ndarray:
-        return np.array(
-            [
-                self.client_id & _MASK32,
-                self.object_id & _MASK32,
-                (self.object_id >> 32) & _MASK32,
-                self.offset & _MASK32,
-                (self.offset >> 32) & _MASK32,
-                self.length & _MASK32,
-                (self.length >> 32) & _MASK32,
-                self.rights & _MASK32,
-                self.expiry & _MASK32,
-                self.nonce & _MASK32,
-            ],
-            dtype=np.uint32,
+    @functools.cached_property
+    def _words(self) -> tuple[int, ...]:
+        """The wire words as plain Python ints (``rights`` may be a
+        :class:`Rights` flag), built once per ticket."""
+        oid, off, length = int(self.object_id), int(self.offset), int(self.length)
+        return (
+            int(self.client_id) & _MASK32,
+            oid & _MASK32,
+            (oid >> 32) & _MASK32,
+            off & _MASK32,
+            (off >> 32) & _MASK32,
+            length & _MASK32,
+            (length >> 32) & _MASK32,
+            int(self.rights) & _MASK32,
+            int(self.expiry) & _MASK32,
+            int(self.nonce) & _MASK32,
         )
 
+    def words(self) -> np.ndarray:
+        return np.array(self._words, dtype=np.uint32)
+
     def pack(self) -> bytes:
-        return _CAP_STRUCT.pack(*(int(w) for w in self.words())) + struct.pack(
-            "<2I", *self.tag
-        )
+        return _CAP_STRUCT.pack(*self._words) + struct.pack("<2I", *self.tag)
 
     @staticmethod
     def unpack(raw: bytes) -> "Capability":
@@ -191,6 +217,7 @@ class CapabilityAuthority:
         self.key = np.asarray(key, dtype=np.uint32)
         if self.key.shape != (4,):
             raise ValueError("key must be 4 uint32 words")
+        self._key_ints = tuple(self.key.tolist())
         #: capability checks made by :meth:`verify`
         self.verifications = 0
 
@@ -205,8 +232,7 @@ class CapabilityAuthority:
         nonce: int = 0,
     ) -> Capability:
         cap = Capability(client_id, object_id, offset, length, rights, expiry, nonce)
-        tag = sponge_mac(cap.words(), self.key)
-        return dataclasses.replace(cap, tag=(int(tag[0]), int(tag[1])))
+        return dataclasses.replace(cap, tag=_mac_ints(cap._words, self._key_ints))
 
     def verify(
         self,
@@ -221,8 +247,8 @@ class CapabilityAuthority:
         """Full header-handler check: MAC, expiry, rights, extent, identity."""
         self.verifications += 1
         with wall.span("auth.verify", "packet"):
-            tag = sponge_mac(cap.words(), self.key)
-        if (int(tag[0]), int(tag[1])) != cap.tag:
+            tag = _mac_ints(cap._words, self._key_ints)
+        if tag != cap.tag:
             return False
         if now > cap.expiry:
             return False

@@ -1,7 +1,11 @@
 """Capability authentication: issue/verify, forgery rejection, np/jnp parity."""
 
+import dataclasses
+
 import jax.numpy as jnp
 import numpy as np
+import pytest
+
 try:
     from hypothesis import given, settings
     from hypothesis import strategies as st
@@ -11,9 +15,11 @@ except ImportError:  # fall back to the deterministic local shim
 
 from repro.core.auth import (
     CAP_WORDS,
+    TAG_WORDS,
     Capability,
     CapabilityAuthority,
     Rights,
+    _mac_ints,
     sponge_mac,
 )
 
@@ -73,6 +79,37 @@ def test_np_jnp_mac_parity(words):
     t_np = sponge_mac(w, AUTH.key, xp=np)
     t_j = np.asarray(sponge_mac(jnp.asarray(w), jnp.asarray(AUTH.key), xp=jnp))
     assert np.array_equal(t_np, t_j)
+
+
+@pytest.mark.parametrize("nwords", [1, 2, CAP_WORDS, 16, 19])
+def test_scalar_batched_jnp_mac_parity(nwords):
+    """One vector runs on Python ints, a stack of them on numpy's vector
+    rounds, a jnp array on the traced rounds: the same tag, bit for bit."""
+    rng = np.random.default_rng(1000 + nwords)
+    keys = rng.integers(0, 2**32, (4, 4), dtype=np.uint64).astype(np.uint32)
+    for key in keys:
+        words = rng.integers(0, 2**32, (8, nwords),
+                             dtype=np.uint64).astype(np.uint32)
+        scalar = np.stack([sponge_mac(w, key) for w in words])
+        assert scalar.dtype == np.uint32 and scalar.shape == (8, TAG_WORDS)
+        assert np.array_equal(sponge_mac(words, key), scalar)
+        traced = sponge_mac(jnp.asarray(words), jnp.asarray(key), xp=jnp)
+        assert np.array_equal(np.asarray(traced), scalar)
+        assert [_mac_ints(w.tolist(), key.tolist()) for w in words] == [
+            tuple(t) for t in scalar.tolist()]
+
+
+def test_verify_checks_the_mac_every_time():
+    cap = _cap()
+    checks = AUTH.verifications
+    for _ in range(3):
+        assert AUTH.verify(cap, now=1, op_rights=Rights.WRITE)
+    forged = dataclasses.replace(cap, tag=(cap.tag[0], cap.tag[1] ^ 1))
+    assert not AUTH.verify(forged, now=1, op_rights=Rights.WRITE)
+    assert AUTH.verify(cap, now=1, op_rights=Rights.WRITE)
+    assert AUTH.verifications == checks + 5
+    assert np.array_equal(cap.words(), cap.words())
+    assert cap.words() is not cap.words()
 
 
 def test_bulk_verify_kernel():

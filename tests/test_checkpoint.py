@@ -178,3 +178,20 @@ def test_checkpoint_policy_spec_roundtrip():
             assert back.m == pol.m and back.encode == pol.encode
         else:
             assert back.strategy == pol.strategy
+
+
+@pytest.mark.parametrize("offset", [0, 37, 63])
+def test_restore_detects_altered_leaf_bytes(offset):
+    cluster = StorageCluster(num_nodes=8, node_capacity=1 << 23)
+    mgr = CheckpointManager(cluster, CheckpointPolicy(k=4, m=2,
+                                                      stripe_bytes=1 << 16))
+    tree = _tree()
+    mgr.save(3, tree, blocking=True)
+    (leaf,) = [lf for lf in mgr._manifests[3]["leaves"]
+               if lf["path"] == "layer0/w"]
+    layout = cluster.meta.lookup(leaf["stripes"][0]["oid"])
+    shard, at = divmod(offset, layout.chunk_len)
+    coord = layout.data_coords[shard]
+    cluster.nodes[coord.node].storage.mem[coord.addr + at] ^= 0x5A
+    with pytest.raises(IOError, match="integrity check failed for layer0/w"):
+        mgr.restore(3)

@@ -93,6 +93,55 @@ def test_forged_capability_nacked_no_write(cluster):
     assert nodes[6].storage.bytes_written == before
 
 
+@pytest.mark.parametrize("forgery", ["tag_word0_bit0", "tag_word1_bit31",
+                                     "write_only"])
+def test_forged_capability_read_nacked_no_data(cluster, forgery):
+    auth, router, nodes, client, cap = cluster
+    data = np.random.default_rng(3).integers(0, 256, 5000, dtype=np.uint8)
+    client.write(cap, data, [ReplicaCoord(2, 500)])
+    good = auth.issue(client_id=5, object_id=1, offset=0, length=1 << 24,
+                      rights=Rights.READ | Rights.WRITE, expiry=10**10)
+    if forgery == "tag_word0_bit0":
+        bad = dataclasses.replace(good, tag=(good.tag[0] ^ 1, good.tag[1]))
+    elif forgery == "tag_word1_bit31":
+        bad = dataclasses.replace(good, tag=(good.tag[0],
+                                             good.tag[1] ^ (1 << 31)))
+    else:
+        bad = cap                              # a WRITE-only ticket
+    checks, to_clients = auth.verifications, router.packets_to_clients
+    served, nacks = nodes[2].counts["read_done"], nodes[2].counts["nack"]
+    with pytest.raises(IOError, match="denied"):
+        client.read(bad, ReplicaCoord(2, 500), data.size)
+    # one check for the one shard request, answered by a NACK alone
+    assert auth.verifications == checks + 1
+    assert router.packets_to_clients == to_clients + 1
+    assert nodes[2].counts["nack"] == nacks + 1
+    assert nodes[2].counts["read_done"] == served
+    assert np.array_equal(client.read(good, ReplicaCoord(2, 500), data.size),
+                          data)
+    assert auth.verifications == checks + 2
+    assert nodes[2].counts["read_done"] == served + 1
+
+
+def test_cluster_read_checks_each_shard_request():
+    from repro.checkpoint.storage import StorageCluster
+
+    k, m = 4, 2
+    cluster = StorageCluster(num_nodes=8, node_capacity=1 << 20)
+    auth = cluster.meta.authority
+    blob = np.random.default_rng(4).integers(0, 256, k * 1000, np.uint8)
+    before = auth.verifications
+    (layout,) = cluster.write_object_bulk([blob], k=k, m=m)
+    assert auth.verifications == before + k + m
+    before = auth.verifications
+    assert cluster.read_objects([layout])[0] == blob.tobytes()
+    assert auth.verifications == before + k      # healthy: data shards only
+    cluster.fail_node(layout.data_coords[0].node)
+    before = auth.verifications
+    assert cluster.read_objects([layout])[0] == blob.tobytes()
+    assert auth.verifications == before + k + m - 1
+
+
 def test_req_table_deny_on_full(cluster):
     auth, router, nodes, client, cap = cluster
     small = DFSNode(99, router, auth, req_table_capacity=0)
