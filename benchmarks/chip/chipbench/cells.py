@@ -8,11 +8,13 @@ planted faults of :mod:`chipbench.faults` that it can have.  A cell
 builds its state from the seed (``setup``, warm-up included), serves
 operation ``i`` of the window (``op``, which returns the user bytes it
 moved and raises when it fails), and after the window compares what the
-served path produced with the plain reference (``verify``).  Write kinds
-move to a fresh cluster of the same configuration when their byte budget
-fills (:meth:`Cell.full`, :meth:`Cell.rotate`): the program's metadata
-service never frees an extent, so host memory would otherwise grow with
-the speed of the system.  The harness stops the window's clock while a
+served path produced with the plain reference (``verify``); a traced
+run reads its counters at both ends of each window piece
+(``counters``).  Write kinds move to a fresh cluster of the same
+configuration when their byte budget fills (:meth:`Cell.full`,
+:meth:`Cell.rotate`): the program's metadata service never frees an
+extent, so host memory would otherwise grow with the speed of the
+system.  The harness stops the window's clock while a
 cluster is retired and checked.
 """
 
@@ -111,6 +113,15 @@ class Cell:
 
     def rotate(self) -> None:
         raise NotImplementedError
+
+    def counters(self):
+        """The always-on counters of the cluster the window drives now,
+        as a ``repro.trace.CounterRegistry``; read at both ends of each
+        piece of a traced window.  A kind whose operations reach a layer
+        with counters of its own returns a registry that adds them."""
+        from repro.trace import dataplane_registry
+
+        return dataplane_registry(self.cluster, getattr(self, "mgr", None))
 
     def verify(self) -> None:
         """Compare what the served path produced with the reference,

@@ -28,6 +28,9 @@ from chipbench.cache import CHECKOUT, CompileClock, setup_compile_cache
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 #: failed operations whose traceback is printed; the rest are counted
 TRACEBACKS = 3
+#: program spans a traced window keeps (the save cell's 40 s window on a
+#: v5e holds about 210,000): room for a program several times faster
+SPAN_BUFFER = 4_000_000
 
 
 def process_age_s() -> float | None:
@@ -58,9 +61,17 @@ class Run:
     #: the measured window: (start, end) pieces, perf_counter_ns
     window: list
     setup_s: float
+    #: the benchmark's spans around calls into the program's layers
     spans: list
     trace: reduce.DeviceTrace | None
     device_kind: str
+    #: the program's own spans (``repro.trace.Span``: ``name``, ``t0``,
+    #: ``t1`` on the window's clock) inside the window; None when none
+    #: were recorded or the tracer dropped some
+    program_spans: list | None = None
+    #: the cell's counters (:meth:`chipbench.cells.Cell.counters`):
+    #: name -> sum over the window's pieces
+    counters: dict = dataclasses.field(default_factory=dict)
 
     @property
     def elapsed_s(self) -> float:
@@ -162,7 +173,54 @@ def arrivals(loop: dict, seed: int):
         rng.exponential(gap) for _ in itertools.count()))
 
 
-def measure_window(cell, seconds: float, store, due=None
+class Recorder:
+    """What a ``--trace 1`` run records inside the window's pieces: the
+    benchmark's spans around the program's layers, the program's own
+    spans (a ``repro.trace.Tracer.wall()`` installed) and the cell's
+    counters, summed over the pieces as differences, each piece read on
+    the registry of the cluster it drove.  As a context manager it
+    records from entry to exit; :meth:`pause` and :meth:`resume` leave
+    out the retirement of a full cluster."""
+
+    def __init__(self, cell):
+        from repro.trace import Tracer
+
+        self.cell = cell
+        self.store = spans.SpanStore()
+        self.tracer = Tracer.wall(max_spans=SPAN_BUFFER)
+        self.counters: dict = {}
+        self._opened = None
+
+    def resume(self) -> None:
+        from repro.trace import wall
+
+        reg = self.cell.counters()
+        self._opened = (reg, reg.snapshot())
+        self.store.enabled = True
+        wall.install(self.tracer)
+
+    def pause(self) -> None:
+        from repro.trace import wall
+
+        wall.uninstall()
+        self.store.enabled = False
+        reg, before = self._opened
+        for name, d in reg.diff(before, reg.snapshot()).items():
+            self.counters[name] = self.counters.get(name, 0) + d
+
+    def __enter__(self) -> "Recorder":
+        self.resume()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.pause()
+        return False
+
+    def program_spans(self) -> list | None:
+        return None if self.tracer.dropped else self.tracer.spans
+
+
+def measure_window(cell, seconds: float, recorder=None, due=None
                    ) -> tuple[list, list]:
     """One client serves operations until the first one that completes
     after ``seconds`` of window.  Closed loop (``due`` None): back to
@@ -171,7 +229,8 @@ def measure_window(cell, seconds: float, store, due=None
     later, and its latency counts from its arrival; the window ends at
     ``seconds`` when no operation is in flight then.  The clock stops
     while a full cluster is retired and checked (an arrival due before
-    that counts from the restart)."""
+    that counts from the restart), and so does ``recorder`` (a
+    :class:`Recorder`, in a traced run)."""
     ops, window, failures = [], [], 0
     limit = int(seconds * 1e9)
     piece = time.perf_counter_ns()
@@ -182,11 +241,11 @@ def measure_window(cell, seconds: float, store, due=None
             now = time.perf_counter_ns()
             window.append((piece, now))
             done_ns += now - piece
-            if store is not None:
-                store.enabled = False
+            if recorder is not None:
+                recorder.pause()
             cell.rotate()
-            if store is not None:
-                store.enabled = True
+            if recorder is not None:
+                recorder.resume()
             piece = time.perf_counter_ns()
         if due is None:
             start = time.perf_counter_ns()
@@ -270,20 +329,21 @@ def main(argv: list[str] | None = None, allow_cpu: bool = False,
     print("set-up phases: " + ", ".join(f"{n} {t:.3f} s" for n, t in phases),
           flush=True)
 
-    store = spans.SpanStore() if args.trace else None
+    recorder = Recorder(cell) if args.trace else None
     trace = None
     with contextlib.ExitStack() as stack:
         log_dir = None
-        if store is not None:
-            stack.callback(spans.install(store))
+        if recorder is not None:
+            stack.callback(spans.install(recorder.store))
             log_dir = stack.enter_context(
                 tempfile.TemporaryDirectory(prefix="chipbench-trace-"))
         with profiled(log_dir):
             c0 = clock.snapshot()
             setup_s = time.perf_counter() - t_start
-            ops, window = measure_window(
-                cell, args.seconds, store,
-                arrivals(traffic.get("loop", {}), args.seed))
+            with recorder or contextlib.nullcontext():
+                ops, window = measure_window(
+                    cell, args.seconds, recorder,
+                    arrivals(traffic.get("loop", {}), args.seed))
             c1 = clock.snapshot()
             peak = memory_peak_bytes(entry["chips"])
         if log_dir is not None and info["platform"] == "tpu":
@@ -305,8 +365,15 @@ def main(argv: list[str] | None = None, allow_cpu: bool = False,
     failed = sum(not op.ok for op in ops)
     checks["failed_operations"] = (failed, 0)
 
-    run = Run(ops, window, setup_s,
-              store.spans if store else [], trace, info["kind"])
+    run = Run(ops, window, setup_s, [], trace, info["kind"])
+    if recorder is not None:
+        run.spans = recorder.store.spans
+        run.program_spans = recorder.program_spans()
+        run.counters = recorder.counters
+        print(f"program spans: {len(recorder.tracer.spans)} kept, "
+              f"{recorder.tracer.dropped} dropped"
+              + ("; readers of program spans report nothing"
+                 if run.program_spans is None else ""), flush=True)
     metrics = {}
     for m in cell_metrics(bench, args.workload, bool(args.trace)):
         value = reader(m["name"])(run)
@@ -321,7 +388,13 @@ def main(argv: list[str] | None = None, allow_cpu: bool = False,
         device["window_s"] = run.elapsed_s
         result["breakdown"] = {
             "device_ops": trace.top_ops(window),
-            "idle_gaps": reduce.idle_by_host(trace, run.spans, window)}
+            "idle_gaps": [] if run.program_spans is None else
+            reduce.idle_by_program_span(trace, run.program_spans, window)}
+    if recorder is not None:
+        print(f"counters per operation ({len(ops)} operations): "
+              + ", ".join(f"{name} {v / max(1, len(ops))!r}"
+                          for name, v in sorted(run.counters.items()) if v),
+              flush=True)
     result["checks"] = {name: {"value": v, "limit": lim}
                         for name, (v, lim) in checks.items()}
     for name, (v, lim) in checks.items():

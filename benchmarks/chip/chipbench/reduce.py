@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import bisect
 import glob
+import heapq
 import math
 import os
 
@@ -21,9 +22,8 @@ MODULES_LINE = "XLA Modules"
 #: The jitted codec program (bit-plane pack, Pallas GF matmul, unpack),
 #: found by its XLA module name.
 CODEC_PROGRAM = "_encode_planes_batched"
-#: Host span categories from the innermost layer out: an idle gap of the
-#: device is charged to the innermost span the host was in.
-LAYER_ORDER = ("packet", "gf", "codec", "cluster", "save")
+#: What device idle time is charged to where no program span is open.
+OUTSIDE = "outside the program"
 
 
 # -- plain statistics ------------------------------------------------------
@@ -131,6 +131,35 @@ def in_window(spans, category: str, window):
             and any(ws <= s.start < we for ws, we in window)]
 
 
+# -- the program's spans ---------------------------------------------------
+# ``repro.trace.Span`` records of ``repro.trace.wall``: ``name``, ``t0``,
+# ``t1``, on any thread.
+
+def program_union(spans, names, window) -> list[tuple[int, int]]:
+    """Where a program span named in ``names`` was open, in the window."""
+    return intersect(union((s.t0, s.t1) for s in spans if s.name in names),
+                     window)
+
+
+def last_opened(spans) -> list[tuple[int, int, str]]:
+    """``(start, end, name)`` pieces, in order, between every two
+    consecutive ends of the spans: in each, the span opened last among
+    those open then, on any thread, or :data:`OUTSIDE`."""
+    spans = [s for s in spans if s.t1 > s.t0]
+    by_start = sorted(spans, key=lambda s: s.t0)
+    bounds = sorted({t for s in spans for t in (s.t0, s.t1)})
+    heap: list = []         # (-t0, order, span): the last opened on top
+    out, j = [], 0
+    for a, b in zip(bounds, bounds[1:]):
+        while j < len(by_start) and by_start[j].t0 <= a:
+            heapq.heappush(heap, (-by_start[j].t0, j, by_start[j]))
+            j += 1
+        while heap and heap[0][2].t1 <= a:
+            heapq.heappop(heap)
+        out.append((a, b, heap[0][2].name if heap else OUTSIDE))
+    return out
+
+
 # -- device traces ---------------------------------------------------------
 
 class DeviceTrace:
@@ -178,8 +207,8 @@ class DeviceTrace:
                           recursive=True)
         if len(files) != 1:
             raise ValueError(f"expected one trace under {log_dir}: {files}")
-        data = jax.profiler.ProfileData.from_file(files[0])
-        return cls.from_planes(data.planes, window_start_ns)
+        planes = list(jax.profiler.ProfileData.from_file(files[0]).planes)
+        return cls.from_planes(planes, window_start_ns)
 
     def busy(self, window) -> dict[str, list[tuple[int, int]]]:
         """Per device, the union of its operations inside the window."""
@@ -221,37 +250,31 @@ class DeviceTrace:
         return [[n, t / 1e9] for n, t in top]
 
 
-def idle_by_host(trace: DeviceTrace, spans, window,
-                 limit: int = 10) -> list[list]:
-    """Idle time of the device, charged to what the host was doing: the
-    innermost benchmark span (by :data:`LAYER_ORDER`) open at the time,
-    named by the wrapped call, else ``"outside the program"``.  Averaged
-    over devices."""
+def idle_by_program_span(trace: DeviceTrace, spans, window,
+                         limit: int | None = 10) -> list[list]:
+    """Idle time of the device in the window, charged to what the host
+    was doing: the program span opened last among those open at the
+    time (:func:`last_opened`), else :data:`OUTSIDE`.  Averaged over
+    devices; the ``limit`` largest, largest first."""
     busy = trace.busy(window)
     if not busy:
         return []
-    names = {}
-    for cat in LAYER_ORDER:
-        for s in spans:
-            if s.category == cat:
-                names.setdefault(cat, set()).add(s.name)
-    charged: dict[str, float] = {}
+    pieces = last_opened(spans)
+    charged: dict[str, int] = {}
     for dev_busy in busy.values():
-        idle = gaps(dev_busy, window)
-        covered_before = 0
-        outer: list = []
-        for cat in LAYER_ORDER:
-            for name in sorted(names.get(cat, ())):
-                outer += [(s.start, s.end) for s in spans if s.name == name]
-                covered = measure(intersect(union(outer), idle))
-                if covered > covered_before:
-                    charged[name] = charged.get(name, 0) + (
-                        covered - covered_before)
-                covered_before = covered
-        rest = measure(idle) - covered_before
-        if rest:
-            key = "outside the program"
-            charged[key] = charged.get(key, 0) + rest
+        for s, e in gaps(dev_busy, window):
+            # the pieces overlapping the gap, and outside them OUTSIDE
+            at = max(0, bisect.bisect_right(pieces, (s,)) - 1)
+            rest = e - s
+            while at < len(pieces) and pieces[at][0] < e:
+                a, b, name = pieces[at]
+                t = min(b, e) - max(a, s)
+                if t > 0:
+                    charged[name] = charged.get(name, 0) + t
+                    rest -= t
+                at += 1
+            if rest:
+                charged[OUTSIDE] = charged.get(OUTSIDE, 0) + rest
     n = len(busy)
     top = sorted(charged.items(), key=lambda kv: -kv[1])[:limit]
     return [[name, t / n / 1e9] for name, t in top]

@@ -1,5 +1,6 @@
 """Host spans around calls into the program's layers, from the
-benchmark's side: the program itself records none on this path.
+benchmark's side, read by the metrics that predate the program's own
+spans (which a traced run hands the readers as ``Run.program_spans``).
 
 Only a ``--trace 1`` run installs the wrappers; :func:`install` returns
 the function that takes them out again.

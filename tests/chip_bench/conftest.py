@@ -1,8 +1,11 @@
 """Shared set-up of the chip benchmark's tests: the harness's package on
 the path, the harness steered off the persistent compile cache, and
-tiny sizes for every cell so that a whole run takes a second on the
-CPU."""
+tiny sizes for every cell, so that a whole run takes a second on the
+CPU.  A cell's tiny sizes are its own file, ``tiny/<cell>.json``:
+``{"config": {...}, "traffic": {...}}``, each updating the keys of the
+cell's configuration and traffic files."""
 
+import json
 import os
 import sys
 
@@ -11,31 +14,21 @@ import pytest
 BENCH_DIR = os.path.abspath(os.path.join(
     os.path.dirname(__file__), "..", "..", "benchmarks", "chip"))
 sys.path.insert(0, BENCH_DIR)
+TINY_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)), "tiny")
 
-KiB = 1024
-#: a tiny checkpoint state: two groups, a repeated leaf that takes two
-#: stripe objects on each of two chips, and one that takes a short one
-TINY_STATE = {"groups": ["params", "opt_state/mu"], "dtype": "float32",
-              "fsdp_chips": 2,
-              "leaves": [{"path": "w/{i}", "shape": [96, 64], "repeat": 2},
-                         {"path": "n", "shape": [1002]}]}
-#: cell -> overrides of its configuration and traffic files
-TINY = {
-    "hdfs-rs6-3-1m.stream-write": {"traffic": {
-        "object_bytes": 24 * KiB, "pool_objects": 4,
-        "cluster_bytes": 12 * 36 * KiB, "readback_objects": 3}},
-    "ckpt-rs6-3.save": {
-        "config": {"stripe_bytes": 6 * KiB, "state": TINY_STATE},
-        "traffic": {"cluster_bytes": 300 * KiB, "checked_objects": 4,
-                    "readback_objects": 2}},
-    "hdfs-rs6-3-1m.degraded-read": {
-        "config": {"dataset_objects": 24},
-        "traffic": {"object_bytes": 24 * KiB, "kept_share": 0.5,
-                    "kept_results": 8}},
-    "hdfs-rs6-3-1m.repair": {
-        "config": {"dataset_objects": 24},
-        "traffic": {"object_bytes": 24 * KiB, "readback_objects": 3}},
-}
+
+def tiny_sizes(cell: str) -> dict:
+    """The overrides in ``tiny/<cell>.json``; a cell without that file
+    is refused with the file's path."""
+    path = os.path.join(TINY_DIR, cell + ".json")
+    if not os.path.exists(path):
+        root = os.path.dirname(os.path.dirname(BENCH_DIR))
+        raise FileNotFoundError(
+            f"cell {cell!r} has no tiny sizes: add "
+            f"{os.path.relpath(path, root)} holding "
+            f'{{"config": {{...}}, "traffic": {{...}}}}')
+    with open(path) as f:
+        return json.load(f)
 
 
 @pytest.fixture
@@ -51,7 +44,8 @@ def run_cell(monkeypatch, capsys):
         result = harness.main(
             ["--workload", cell, "--seed", str(seed), "--seconds",
              str(seconds), "--trace", str(trace)],
-            allow_cpu=True, hook=hook, overrides=overrides or TINY[cell])
+            allow_cpu=True, hook=hook,
+            overrides=overrides or tiny_sizes(cell))
         out = capsys.readouterr()
         return result, out.out.strip().splitlines(), out.err
 
@@ -60,4 +54,5 @@ def run_cell(monkeypatch, capsys):
 
 @pytest.fixture
 def tiny():
-    return TINY
+    """``tiny(cell)``: the cell's tiny sizes."""
+    return tiny_sizes

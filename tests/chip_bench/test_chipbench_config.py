@@ -53,6 +53,22 @@ def test_a_full_check_fits_its_time():
     assert 1 <= BENCH["run_seconds"] <= 51
 
 
+@pytest.mark.parametrize("workload", WORKLOADS)
+def test_every_cell_brings_its_tiny_sizes(workload, tiny):
+    sizes = tiny(workload)
+    _, config, traffic = harness.cell_files(BENCH, workload)
+    assert set(sizes) - {"about"} == {"config", "traffic"}
+    # each size updates a key the cell's own files have
+    assert set(sizes["config"]) <= set(config)
+    assert set(sizes["traffic"]) <= set(traffic)
+
+
+def test_a_cell_without_tiny_sizes_is_named_with_the_file_to_add(tiny):
+    with pytest.raises(FileNotFoundError,
+                       match="add tests/chip_bench/tiny/no-such-cell.json"):
+        tiny("no-such-cell")
+
+
 def test_unknown_workload_is_refused():
     with pytest.raises(SystemExit):
         harness.cell_files(BENCH, "no-such-cell")
@@ -74,8 +90,8 @@ def _plan(cell) -> str:
 @pytest.mark.parametrize("workload", WORKLOADS)
 def test_traffic_is_drawn_from_the_seed_alone(workload, tiny):
     _, config, traffic = harness.cell_files(BENCH, workload)
-    config.update(tiny[workload].get("config", {}))
-    traffic.update(tiny[workload].get("traffic", {}))
+    config.update(tiny(workload).get("config", {}))
+    traffic.update(tiny(workload).get("traffic", {}))
     plans = []
     for seed in (2**31 + 5, 2**31 + 5, 2**31 + 6):
         cell = cells.make(dict(config), dict(traffic), seed)

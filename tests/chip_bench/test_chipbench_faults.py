@@ -64,6 +64,6 @@ def test_control_script_reports_each_seed(monkeypatch, capsys, tiny):
     results = control.main(
         ["--workload", "hdfs-rs6-3-1m.repair", "--fault", "altered_answer",
          "--seconds", "0.2", "--seeds", "4", "5"],
-        allow_cpu=True, overrides=tiny["hdfs-rs6-3-1m.repair"])
+        allow_cpu=True, overrides=tiny("hdfs-rs6-3-1m.repair"))
     assert [r["correct"] for r in results] == [False, False]
     assert "2 of 2 runs judged not correct" in capsys.readouterr().err

@@ -1,21 +1,24 @@
-"""The program's own records as the benchmark reads them: the data
-plane's counters as differences over a window that moves to a fresh
-cluster part way, the ``codec_kernel_share`` reader on hand-built device
-events and on the committed TPU traces (``data/encode_s1.xplane.pb``:
-three encodes, no program spans; ``program_trace/program_s1.xplane.pb``:
-writes and a degraded read with the program's tracer installed, kept
-apart because the reduce tests read every trace under ``data/``), and
-the program's spans in that trace's host plane, on the device's clock."""
+"""The program's own records as the benchmark reads them: the counters
+and spans a traced run's harness hands the readers, exact over a window
+that moves to a fresh cluster part way; the readers of the program's
+spans (``packet_us``, ``codec_host_share``, ``ckpt_snapshot_share``) and
+the charger of device idle time, by hand, on the committed TPU trace
+``program_trace/program_s1.xplane.pb`` (writes and a degraded read with
+the program's tracer installed, kept apart because the reduce tests read
+every trace under ``data/``), where the program's spans lie in the host
+plane on the device's clock; and the ``codec_kernel_share`` reader on
+hand-built device events and on both committed traces
+(``data/encode_s1.xplane.pb``: three encodes, no program spans)."""
 
 import os
 
 import pytest
 
-from chipbench import cells, harness, reduce
+from chipbench import harness, reduce
 from chipbench.harness import Run, reader
 from repro.core.packets import WriteRequestHeader, num_packets
 from repro.kernels import ops
-from repro.trace import Tracer, dataplane_registry, wall
+from repro.trace import Span, wall
 
 HERE = os.path.dirname(__file__)
 TPU = "/device:TPU:0"
@@ -50,76 +53,58 @@ def _fixture_run(planes):
     return Run([], [(int(s), int(e))], 1.0, [], trace, "TPU v5 lite")
 
 
-def _counted_window(cell, seconds, tracer):
-    """``measure_window`` with the data plane's counters summed over its
-    pieces, each read on its own cluster's registry, and the tracer
-    installed; a ``rotate()`` (which retires and checks a full cluster)
-    is left out of both."""
-    total, opened = {}, []
-
-    def start():
-        reg = dataplane_registry(cell.cluster)
-        opened.append((reg, reg.snapshot()))
-        wall.install(tracer)
-
-    def stop():
-        wall.uninstall()
-        reg, before = opened.pop()
-        for name, d in reg.diff(before, reg.snapshot()).items():
-            total[name] = total.get(name, 0) + d
-
-    rotate = cell.rotate
-
-    def paused_rotate():
-        stop()
-        rotate()
-        start()
-
-    cell.rotate = paused_rotate
-    start()
-    try:
-        ops_, window = harness.measure_window(cell, seconds, None)
-    finally:
-        stop()
-        del cell.rotate
-    return ops_, window, total
-
-
-def test_window_counters_stay_exact_across_a_mid_window_rotate(monkeypatch):
-    """Tiny 6+3 writes on the kernels (interpreted), a cluster too small
-    for the window: the counters count what the operations must send,
-    and the rotation's own check (which reads every object back) is left
-    out of the counters and of the spans."""
+def test_window_counters_stay_exact_across_a_mid_window_rotate(
+        run_cell, monkeypatch):
+    """A traced run of tiny 6+3 writes on the kernels (interpreted), a
+    cluster too small for the window: the harness's counters count what
+    the operations must send, and the rotation's own check (which reads
+    every object back) is left out of the counters and of the program's
+    spans."""
     monkeypatch.setattr(ops, "dataplane_backend",
                         lambda backend=None: backend or "jax")
+    runs = []
+
+    class Kept(Run):
+        def __init__(self, *args, **kwargs):
+            super().__init__(*args, **kwargs)
+            runs.append(self)
+
+    monkeypatch.setattr(harness, "Run", Kept)
     cell_name = "hdfs-rs6-3-1m.stream-write"
-    entry, config, traffic = harness.cell_files(harness.load_bench(),
-                                                cell_name)
+    _, _, traffic = harness.cell_files(harness.load_bench(), cell_name)
     # 24 KiB objects, four to a cluster
-    traffic.update(object_bytes=24 << 10, pool_objects=4, readback_objects=3,
-                   cluster_bytes=4 * 9 * (4 << 10))
-    cell = cells.make(config, traffic, 4000000007)
-    cell.setup()
-    tracer = Tracer.wall()
-    ops_, window, c = _counted_window(cell, 0.5, tracer)
+    sizes = dict(object_bytes=24 << 10, pool_objects=4, readback_objects=3,
+                 cluster_bytes=4 * 9 * (4 << 10))
+    result, out, _ = run_cell(cell_name, 4000000007, trace=1, seconds=0.5,
+                              overrides={"traffic": sizes})
+    assert result["correct"]
+    (run,) = runs
+    window, c = run.window, run.counters
     assert len(window) >= 2, "the window never moved to a fresh cluster"
-    writes = len(ops_) * traffic["objects_per_op"]
-    shard = cell.chunk(traffic["object_bytes"])
-    shards = writes * (cell.k + cell.m)
+    writes = len(run.ops) * traffic["objects_per_op"]
+    k, m = 6, 3
+    shard = 4 << 10                     # 24 KiB over 6 data cells
+    shards = writes * (k + m)
     assert c["packets.to_nodes"] == shards * num_packets(
         shard, WriteRequestHeader(0, shard).packed_size())
     assert c["packets.to_clients"] == shards            # one ack a shard
     assert c["auth.verifications"] == c["node.write_done"] == shards
     assert c["node.read_done"] == 0                     # no check read
-    assert c["codec.dispatches"] == len(ops_)
+    assert c["codec.dispatches"] == len(run.ops)
     assert c["codec.stripes"] == writes
-    assert c["codec.h2d_bytes"] == writes * cell.k * shard
-    assert c["codec.d2h_bytes"] == writes * cell.m * shard
+    assert c["codec.h2d_bytes"] == writes * k * shard
+    assert c["codec.d2h_bytes"] == writes * m * shard
     # spans only inside the window's pieces, none in the rotations
-    assert tracer.spans
-    for s in tracer.spans:
+    assert run.program_spans
+    for s in run.program_spans:
         assert any(a <= s.t0 <= s.t1 <= b for a, b in window), s.name
-    assert sum(s.name == "cluster.write" for s in tracer.spans) == len(ops_)
+    assert sum(s.name == "cluster.write"
+               for s in run.program_spans) == len(run.ops)
+    assert f"program spans: {len(run.program_spans)} kept, 0 dropped" in out
+    per_op = next(line for line in out
+                  if line.startswith("counters per operation"))
+    assert f"codec.dispatches {1.0!r}" in per_op
+    assert wall.installed() is None
 
 
 @pytest.mark.parametrize("name", ["codec_kernel_share.write",
@@ -212,3 +197,87 @@ def test_program_fixture_spans_and_device_share_one_clock(program_planes):
     for (ps, pe), (ls, _), (_, de), (cs, ce) in zip(
             progs, by["codec.launch"], by["codec.d2h"], coding):
         assert cs <= ls < ps < pe <= de <= ce
+
+
+def _program_spans(planes):
+    """The fixture's program spans, as the program's tracer records them."""
+    return [Span(n, "", s, e) for n, s, e in _host_notes(planes, PATH_SPANS)]
+
+
+def test_idle_time_of_the_fixture_goes_to_program_spans(program_planes):
+    run = _fixture_run(program_planes)
+    charged = reduce.idle_by_program_span(
+        run.trace, _program_spans(program_planes), run.window, limit=None)
+    names = {n for n, _ in charged}
+    assert names <= PATH_SPANS | {reduce.OUTSIDE}
+    assert {"dfs.write", "dfs.read", "codec.d2h"} <= names
+    idle = sum(t for _, t in charged)
+    assert idle + run.trace.busy_s(run.window) == pytest.approx(
+        run.elapsed_s, rel=0.01)
+    top = reduce.idle_by_program_span(
+        run.trace, _program_spans(program_planes), run.window)
+    assert top == sorted(charged, key=lambda nt: -nt[1])[:10]
+
+
+def _merged(intervals):
+    """Sorted, merged intervals, by a plain loop."""
+    out = []
+    for s, e in sorted(intervals):
+        if out and s <= out[-1][1]:
+            out[-1] = (out[-1][0], max(out[-1][1], e))
+        else:
+            out.append((s, e))
+    return out
+
+
+def test_packet_us_on_the_fixture_by_hand(program_planes):
+    run = _fixture_run(program_planes)
+    run.program_spans = _program_spans(program_planes)
+    run.counters = {"packets.to_nodes": 9000, "packets.to_clients": 1000}
+    dfs = sorted((s.t0, s.t1) for s in run.program_spans
+                 if s.name in ("dfs.write", "dfs.read"))
+    # one client thread: one shard at a time, none inside another
+    assert all(e <= s for (_, e), (s, _) in zip(dfs, dfs[1:]))
+    by_hand = sum(e - s for s, e in dfs) / 10000 / 1e3
+    assert reader("packet_us.write")(run) == pytest.approx(by_hand)
+    run.counters = {}
+    assert reader("packet_us.read")(run) is None
+    run.program_spans = None
+    assert reader("packet_us.repair")(run) is None
+
+
+def test_codec_host_share_on_the_fixture_by_hand(program_planes):
+    run = _fixture_run(program_planes)
+    run.program_spans = _program_spans(program_planes)
+    ((_, ws, we),) = _host_notes(program_planes, {reduce.WINDOW_ANNOTATION})
+    ops_ = [(e.start_ns, e.start_ns + e.duration_ns) for p in program_planes
+            if p.name == TPU for line in p.lines
+            if line.name == reduce.OPS_LINE for e in line.events]
+    idle = 0
+    for s in run.program_spans:
+        if s.name not in ("rs.encode", "rs.decode"):
+            continue
+        inside = _merged((max(a, s.t0), min(b, s.t1)) for a, b in ops_
+                         if a < s.t1 and b > s.t0)
+        idle += (s.t1 - s.t0) - sum(b - a for a, b in inside)
+    share = reader("codec_host_share.write")(run)
+    assert share == pytest.approx(100 * idle / (we - ws))
+    assert 0 < share < 100
+    run.trace = None
+    assert reader("codec_host_share.read")(run) is None
+
+
+def test_ckpt_snapshot_share_by_hand(program_planes):
+    run = _fixture_run(program_planes)
+    run.program_spans = _program_spans(program_planes)
+    read = reader("ckpt_snapshot_share")
+    assert read(run) is None                        # no save in the fixture
+    run.program_spans = [Span("ckpt.save", "entry", 0, 100),
+                         Span("ckpt.snapshot", "entry", 10, 17),
+                         Span("ckpt.leaf", "entry", 20, 90),
+                         Span("ckpt.save", "entry", 200, 300),
+                         Span("ckpt.snapshot", "entry", 205, 208)]
+    run.window = [(0, 1000)]
+    assert read(run) == pytest.approx(100 * 10 / 200)
+    run.window = [(0, 150)]                         # the first save alone
+    assert read(run) == pytest.approx(7)

@@ -108,18 +108,29 @@ def test_recorded_trace_reduces_to_its_codec_program_and_busy_time():
 
 
 def test_idle_gaps_go_to_the_innermost_host_span():
-    trace = reduce.DeviceTrace({"/device:TPU:0": [("op", 10, 20), ("op", 60, 70)]},
-                               {})
-    spans = [Span("cluster", "StorageCluster.read_objects", 0, 100),
-             Span("packet", "DFSClient.read", 25, 55),
-             Span("codec", "RSCode.decode_stripes", 5, 25)]
-    got = dict(reduce.idle_by_host(trace, spans, [(0, 100)]))
-    # idle: 0-10, 20-60, 70-100; the packet span takes 25-55, the codec
-    # span 5-10 and 20-25, the cluster span what is left
-    assert got == {"DFSClient.read": 30e-9, "RSCode.decode_stripes": 10e-9,
-                   "StorageCluster.read_objects": 40e-9}
-    got = dict(reduce.idle_by_host(trace, [], [(0, 100)]))
-    assert got == {"outside the program": 80e-9}
+    """Each idle instant goes to the program span opened last among
+    those open then, on any thread."""
+    from repro.trace import Span as ProgramSpan
+
+    trace = reduce.DeviceTrace({"/device:TPU:0": [("op", 10, 20),
+                                                  ("op", 60, 70)]}, {})
+    spans = [ProgramSpan("cluster.read", "entry", 0, 100),
+             ProgramSpan("dfs.read", "packet", 25, 55),
+             ProgramSpan("rs.decode", "coding", 5, 25),
+             # another thread: opened last from 50 to 58
+             ProgramSpan("ckpt.leaf", "entry", 50, 58),
+             ProgramSpan("empty", "entry", 30, 30)]
+    got = dict(reduce.idle_by_program_span(trace, spans, [(0, 100)]))
+    # idle: 0-10, 20-60, 70-100; rs.decode takes 5-10 and 20-25,
+    # dfs.read 25-50, ckpt.leaf 50-58, cluster.read what is left
+    assert got == {"rs.decode": 10e-9, "dfs.read": 25e-9,
+                   "ckpt.leaf": 8e-9, "cluster.read": 37e-9}
+    got = dict(reduce.idle_by_program_span(trace, spans, [(0, 120)]))
+    assert got[reduce.OUTSIDE] == 20e-9
+    got = dict(reduce.idle_by_program_span(trace, [], [(0, 100)]))
+    assert got == {reduce.OUTSIDE: 80e-9}
+    assert reduce.idle_by_program_span(reduce.DeviceTrace({}, {}), spans,
+                                       [(0, 100)]) == []
 
 
 def test_codec_roofline_reader_from_spans_and_trace():

@@ -13,8 +13,7 @@ import pytest
 
 from chipbench import harness
 
-CELLS = ["hdfs-rs6-3-1m.stream-write", "ckpt-rs6-3.save",
-         "hdfs-rs6-3-1m.degraded-read", "hdfs-rs6-3-1m.repair"]
+CELLS = [w["name"] for w in harness.load_bench()["workloads"]]
 ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", ".."))
 
 
@@ -44,20 +43,30 @@ def test_untraced_run_is_correct_and_reports_its_end_to_end_metrics(
 
 @pytest.mark.parametrize("cell", CELLS)
 def test_traced_run_reports_host_side_layer_metrics(run_cell, cell):
-    result, _, _ = run_cell(cell, seed=5, trace=1)
+    result, out, _ = run_cell(cell, seed=5, trace=1)
     assert result["correct"]
     bench = harness.load_bench()
-    declared = {m["name"] for m in harness.cell_metrics(bench, cell, True)}
+    declared = {m["name"]: m for m in harness.cell_metrics(bench, cell, True)}
     got = set(result["metrics"])
-    assert got <= declared
-    # the CPU has no device trace: only the host-clock metrics read
+    assert got <= set(declared)
+    # the CPU has no device trace: only the host side's metrics read,
+    # from the benchmark's spans and from the program's spans and counters
     assert {n.split(".")[0] for n in got} >= {"packet_plane_share",
-                                               "codec_share"}
-    assert not any(n.startswith(("codec_roofline", "device_idle")) for n in got)
+                                               "codec_share", "packet_us"}
+    assert not any(n.startswith(("codec_roofline", "device_idle",
+                                 "codec_kernel_share", "codec_host_share"))
+                   for n in got)
     for name in got:
-        assert 0 < result["metrics"][name]["value"] <= 100, name
+        value = result["metrics"][name]["value"]
+        assert value > 0, name
+        if declared[name]["unit"] == "%":
+            assert value <= 100, name
     if cell == "ckpt-rs6-3.save":
-        assert "ckpt_self_share" in got
+        assert {"ckpt_self_share", "ckpt_snapshot_share"} <= got
+    assert any(line.startswith("program spans: ") and
+               line.endswith(" kept, 0 dropped") for line in out)
+    assert out[-2].startswith("counters per operation")
+    assert "packets.to_nodes" in out[-2]
 
 
 def test_write_cell_checks_every_retired_cluster(run_cell, monkeypatch):
