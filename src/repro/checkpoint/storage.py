@@ -45,6 +45,13 @@ class ObjectLayout:
     ec_k: int = 0
     ec_m: int = 0
     chunk_len: int = 0  # per-node chunk length (EC) or full size (repl)
+    #: EC: rows of k data cells of ``chunk_len`` bytes; shard ``i`` is its
+    #: cell of every row, in row order, cut to ``slot_lens[i]``
+    stripes: int = 1
+    #: EC: bytes each of the k + m shards stores.  0 is an empty internal
+    #: block (a short file's unused data cells): it keeps its placed node,
+    #: takes no extent (``addr`` -1) and no write, and reads as zeros
+    slot_lens: tuple[int, ...] = ()
     #: set by repair when the object exceeded its loss tolerance: reads
     #: raise and the audit ledger pins the bytes as lost — re-provisioned
     #: nodes must not resurrect zeroed shards as "readable"
@@ -93,17 +100,29 @@ class MetadataService:
         k: int,
         m: int = 0,
         strategy: ReplStrategy = ReplStrategy.RING,
+        stripes: int = 1,
+        chunk_len: int | None = None,
+        slot_lens: tuple[int, ...] | None = None,
     ) -> ObjectLayout:
+        """Place and allocate one object.  An EC object is by default one
+        row of k equal cells (:func:`repro.core.erasure.split_stripe`);
+        ``stripes``, ``chunk_len`` and ``slot_lens`` describe any other
+        row layout (HDFS's cell striping) and the bytes each shard keeps."""
         oid = self._next_oid
         self._next_oid += 1
         if resiliency == Resiliency.ERASURE_CODING:
-            chunk = -(-size // k)
-            chunk = -(-chunk // 32) * 32  # stripe alignment
+            if chunk_len is None:
+                chunk_len = -(-size // k)
+                chunk_len = -(-chunk_len // 32) * 32  # stripe alignment
+            if slot_lens is None:
+                slot_lens = (chunk_len,) * (k + m)
             nodes = self._place(k + m)
-            data = [ReplicaCoord(n, self._extent(n, chunk)) for n in nodes[:k]]
-            par = [ReplicaCoord(n, self._extent(n, chunk)) for n in nodes[k:]]
-            layout = ObjectLayout(oid, size, resiliency, strategy, data, par,
-                                  ec_k=k, ec_m=m, chunk_len=chunk)
+            coords = [ReplicaCoord(n, self._extent(n, ln) if ln else -1)
+                      for n, ln in zip(nodes, slot_lens)]
+            layout = ObjectLayout(oid, size, resiliency, strategy,
+                                  coords[:k], coords[k:], ec_k=k, ec_m=m,
+                                  chunk_len=chunk_len, stripes=stripes,
+                                  slot_lens=tuple(slot_lens))
         elif resiliency == Resiliency.REPLICATION:
             nodes = self._place(k)
             data = [ReplicaCoord(n, self._extent(n, size)) for n in nodes]
@@ -134,6 +153,14 @@ class MetadataService:
             rights=rights,
             expiry=int(time.time()) + ttl_s,
         )
+
+
+def _shard_of(cell_rows: np.ndarray, length: int) -> np.ndarray:
+    """A shard's bytes from its (S, L) cells of every row: the rows in
+    order, cut to ``length``."""
+    if cell_rows.shape[0] == 1:
+        return cell_rows[0, :length]
+    return cell_rows.reshape(-1)[:length]
 
 
 def _io_locked(fn):
@@ -272,79 +299,102 @@ class StorageCluster:
         m: int = 2,
         backend: str | None = None,
     ) -> list[ObjectLayout]:
-        """Batched client-side EC — the ``RS(engine='client')`` plan.
+        """Batched client-side EC — the ``RS(engine='client')`` plan: each
+        blob is one row of k equal cells
+        (:func:`~repro.core.erasure.split_stripe`), written by
+        :meth:`write_stripes`."""
+        from repro.core.erasure import split_stripe
 
-        All same-geometry stripes are encoded in *one*
-        ``RSCode.encode_stripes`` call (backend="jax" is a single fused
-        kernel dispatch per chunk-length group; None takes the platform's
-        data-plane default, the kernels on a TPU), then every data/parity
-        shard is written as an authenticated plain write through the
-        policy engine."""
         with wall.span("cluster.write", "entry"):
-            return self._write_object_bulk(blobs, k, m, backend)
+            arrs = [
+                np.frombuffer(bytes(b), np.uint8)
+                if isinstance(b, (bytes, bytearray))
+                else np.asarray(b, np.uint8).ravel()
+                for b in blobs
+            ]
+            return self._write_stripes([split_stripe(a, k)[None] for a in arrs],
+                                       [a.size for a in arrs], k, m, None,
+                                       backend)
 
-    def _write_object_bulk(self, blobs, k: int, m: int,
-                           backend: str | None) -> list[ObjectLayout]:
-        from repro.core.erasure import RSCode, split_stripe
+    @_io_locked
+    def write_stripes(
+        self,
+        rows: list[np.ndarray],
+        sizes: list[int],
+        k: int,
+        m: int,
+        slot_lens: list[tuple[int, ...]] | None = None,
+        backend: str | None = None,
+    ) -> list[ObjectLayout]:
+        """Encode and write objects already split into rows of cells.
 
-        arrs = [
-            np.frombuffer(bytes(b), np.uint8)
-            if isinstance(b, (bytes, bytearray))
-            else np.asarray(b, np.uint8).ravel()
-            for b in blobs
-        ]
-        layouts = [
-            self.meta.create_object(
-                int(a.size), Resiliency.ERASURE_CODING, k, m,
-                ReplStrategy.RING,
-            )
-            for a in arrs
-        ]
-        # Group stripes by chunk length -> one batched encode each.
-        chunks_list: list[np.ndarray] = []
+        Object ``i`` holds ``sizes[i]`` bytes as ``rows[i]``, (S, k, L)
+        data cells; its shard ``j`` is its cell of every row, cut to
+        ``slot_lens[i][j]`` bytes (default: whole), and an empty shard
+        takes no extent and no write.  All rows of one width are encoded
+        in *one* ``RSCode.encode_stripes`` call (backend="jax" is a
+        single fused kernel dispatch per piece; None takes the platform's
+        data-plane default, the kernels on a TPU), then every non-empty
+        data/parity shard is written as an authenticated plain write
+        through the policy engine."""
+        with wall.span("cluster.write", "entry"):
+            return self._write_stripes(rows, sizes, k, m, slot_lens, backend)
+
+    def _write_stripes(self, rows, sizes, k: int, m: int, slot_lens,
+                       backend: str | None) -> list[ObjectLayout]:
+        from repro.core.erasure import RSCode
+
+        lens = slot_lens or [None] * len(rows)
+
+        def create(i: int) -> ObjectLayout:
+            return self.meta.create_object(
+                int(sizes[i]), Resiliency.ERASURE_CODING, k, m,
+                ReplStrategy.RING, stripes=rows[i].shape[0],
+                chunk_len=rows[i].shape[2], slot_lens=lens[i])
+
+        layouts = [create(i) for i in range(len(rows))]
+        # Group rows by width -> one batched encode each.
         groups: dict[int, list[int]] = {}
-        for idx, (a, lay) in enumerate(zip(arrs, layouts)):
-            chunks = split_stripe(a, k)
-            assert chunks.shape[1] == lay.chunk_len, (
-                chunks.shape, lay.chunk_len)
-            chunks_list.append(chunks)
-            groups.setdefault(chunks.shape[1], []).append(idx)
+        for i, r in enumerate(rows):
+            groups.setdefault(r.shape[2], []).append(i)
         code = RSCode(k, m)
         parities: dict[int, np.ndarray] = {}
         for length, idxs in groups.items():
             if length == 0:
                 for i in idxs:
-                    parities[i] = np.zeros((m, 0), np.uint8)
+                    parities[i] = np.zeros((rows[i].shape[0], m, 0), np.uint8)
                 continue
-            batch = np.stack([chunks_list[i] for i in idxs])   # (S, k, L)
+            batch = np.concatenate([rows[i] for i in idxs])    # (S, k, L)
             par = code.encode_stripes(batch, backend=backend)  # (S, m, L)
-            for s, i in enumerate(idxs):
-                parities[i] = par[s]
+            lo = 0
+            for i in idxs:
+                parities[i] = par[lo:lo + rows[i].shape[0]]
+                lo += rows[i].shape[0]
         for i, lay in enumerate(layouts):
             try:
-                self._write_bulk_shards(lay, chunks_list[i], parities[i])
+                self._write_bulk_shards(lay, rows[i], parities[i])
             except IOError:
                 # mid-batch crash of a placed node: re-place this object on
-                # live nodes (same size -> same chunk length) and retry once
+                # live nodes (same rows -> same shard lengths), retry once
                 del self.meta._objects[lay.object_id]
-                lay = self.meta.create_object(
-                    lay.size, Resiliency.ERASURE_CODING, k, m,
-                    ReplStrategy.RING,
-                )
-                assert lay.chunk_len == chunks_list[i].shape[1]
-                layouts[i] = lay
-                self._write_bulk_shards(lay, chunks_list[i], parities[i])
+                lay = layouts[i] = create(i)
+                self._write_bulk_shards(lay, rows[i], parities[i])
         return layouts
 
     def _write_bulk_shards(
-        self, lay: ObjectLayout, chunks: np.ndarray, parity: np.ndarray
+        self, lay: ObjectLayout, data: np.ndarray, parity: np.ndarray
     ) -> None:
         before = len(self.client.acks())
-        for j, coord in enumerate(lay.data_coords):
-            self.client.write(self.capability, chunks[j], [coord])
-        for pi, coord in enumerate(lay.parity_coords):
-            self.client.write(self.capability, parity[pi], [coord])
-        self._check_acks(lay, before, lay.ec_k + lay.ec_m)
+        cells = [data[:, j] for j in range(lay.ec_k)] + \
+            [parity[:, i] for i in range(lay.ec_m)]
+        coords = self._layout_coords(lay)
+        written = 0
+        for cell_rows, coord, length in zip(cells, coords, lay.slot_lens):
+            if length:
+                self.client.write(self.capability,
+                                  _shard_of(cell_rows, length), [coord])
+                written += 1
+        self._check_acks(lay, before, written)
 
     def set_failures(self, failures) -> None:
         """Attach a :class:`repro.policy.FailureModel` to the functional
@@ -380,6 +430,21 @@ class StorageCluster:
         self.read_timeouts += 1
         return None
 
+    def _read_slot(self, layout: ObjectLayout, idx: int) -> np.ndarray | None:
+        """EC shard ``idx`` of ``layout`` as its (S, L) cells of every row
+        (zero past the shard's length), or ``None`` when it cannot be
+        read.  An empty internal block is known zeros and is not read."""
+        rows, width = layout.stripes, layout.chunk_len
+        length = layout.slot_lens[idx]
+        if not length:
+            return np.zeros((rows, width), np.uint8)
+        got = self._read_shard(self._layout_coords(layout)[idx], length)
+        if got is None or length == rows * width:
+            return None if got is None else got.reshape(rows, width)
+        out = np.zeros(rows * width, np.uint8)
+        out[:length] = got
+        return out.reshape(rows, width)
+
     def read_object(self, layout: ObjectLayout, verify: bool = True) -> bytes:
         """Read one object (degraded-mode capable); see
         :meth:`read_objects`."""
@@ -413,7 +478,7 @@ class StorageCluster:
         from repro.core.erasure import RSCode
 
         out: list[bytes | None] = [None] * len(layouts)
-        # (k, m, chunk_len, missing-pattern) -> [(pos, shards)]
+        # (k, m, rows, chunk_len, missing-pattern) -> [(pos, shards)]
         groups: dict[tuple, list[tuple[int, list]]] = {}
         for pos, layout in enumerate(layouts):
             if layout.lost:
@@ -422,20 +487,20 @@ class StorageCluster:
                     f"tolerance; repair could not reconstruct it)"
                 )
             if layout.resiliency == Resiliency.ERASURE_CODING:
-                chunk = layout.chunk_len
-                data_shards = [self._read_shard(c, chunk)
-                               for c in layout.data_coords]
+                k = layout.ec_k
+                data_shards = [self._read_slot(layout, j) for j in range(k)]
                 if all(s is not None for s in data_shards):
-                    # healthy fast path: k data reads, no parity traffic,
-                    # no decode
-                    out[pos] = np.concatenate(
-                        data_shards)[: layout.size].tobytes()
+                    # healthy fast path: the data reads, no parity
+                    # traffic, no decode
+                    out[pos] = np.stack(data_shards, axis=1).reshape(-1)[
+                        : layout.size].tobytes()
                     continue
                 # degraded: fetch parity lazily, group by erasure pattern
-                shards = data_shards + [self._read_shard(c, chunk)
-                                        for c in layout.parity_coords]
+                shards = data_shards + [self._read_slot(layout, k + i)
+                                        for i in range(layout.ec_m)]
                 pattern = tuple(i for i, s in enumerate(shards) if s is None)
-                key = (layout.ec_k, layout.ec_m, chunk, pattern)
+                key = (k, layout.ec_m, layout.stripes, layout.chunk_len,
+                       pattern)
                 groups.setdefault(key, []).append((pos, shards))
             elif layout.resiliency == Resiliency.REPLICATION:
                 for coord in layout.data_coords:
@@ -451,12 +516,8 @@ class StorageCluster:
                 if got is None:
                     raise IOError(f"object {layout.object_id}: node failed")
                 out[pos] = got.tobytes()
-        for (k, m, chunk, pattern), members in groups.items():
+        for (k, m, rows, _, pattern), members in groups.items():
             code = RSCode(k, m)
-            if chunk == 0:
-                for pos, _ in members:
-                    out[pos] = b""
-                continue
             # one batched decode per (geometry, chunk, erasure pattern)
             try:
                 batched, datam = self._decode_shard_group(
@@ -482,7 +543,8 @@ class StorageCluster:
                         )
             for s, (pos, _) in enumerate(members):
                 layout = layouts[pos]
-                out[pos] = datam[s].reshape(-1)[: layout.size].tobytes()
+                out[pos] = datam[s * rows:(s + 1) * rows].reshape(-1)[
+                    : layout.size].tobytes()
         return out  # type: ignore[return-value]
 
     # -- failure injection / recovery ------------------------------------------
@@ -568,12 +630,13 @@ class StorageCluster:
 
     @staticmethod
     def _decode_shard_group(code, shard_lists, pattern, backend=None):
-        """Stack each slot's per-member shards into an (S, L) batch and
-        reconstruct the whole (geometry, chunk, erasure-pattern) group in
-        ONE ``decode_stripes`` call.  Returns (batched_slots, (S, k, L))."""
+        """Stack each slot's per-member (rows, L) shards into an (S, L)
+        batch and reconstruct the whole (geometry, chunk, erasure-pattern)
+        group in ONE ``decode_stripes`` call.  Returns (batched_slots,
+        (S, k, L)), member by member and row by row."""
         batched = [
             None if i in pattern
-            else np.stack([shards[i] for shards in shard_lists])
+            else np.concatenate([shards[i] for shards in shard_lists])
             for i in range(code.n)
         ]
         return batched, code.decode_stripes(batched, backend=backend)
@@ -636,18 +699,20 @@ class StorageCluster:
                 if coord.node != node_id or layout.lost:
                     continue
                 if layout.resiliency == Resiliency.ERASURE_CODING:
-                    chunk = layout.chunk_len
+                    if not layout.slot_lens[idx]:
+                        continue        # an empty internal block: nothing lost
                     shards = [
-                        None if c.node == node_id
-                        else self._read_shard(c, chunk)
-                        for c in coords
+                        None if c.node == node_id and layout.slot_lens[i]
+                        else self._read_slot(layout, i)
+                        for i, c in enumerate(coords)
                     ]
                     if sum(s is not None for s in shards) < layout.ec_k:
                         self._mark_unrecoverable(layout, in_place, stats)
                         continue
                     pattern = tuple(
                         i for i, s in enumerate(shards) if s is None)
-                    key = (layout.ec_k, layout.ec_m, chunk, pattern)
+                    key = (layout.ec_k, layout.ec_m, layout.stripes,
+                           layout.chunk_len, pattern)
                     ec_groups.setdefault(key, []).append(
                         (layout, idx, shards))
                 elif layout.resiliency == Resiliency.REPLICATION:
@@ -674,7 +739,7 @@ class StorageCluster:
             self.router.heal(node_id)
         # Reconstruct the EC groups batched; the caller writes back.
         tasks: list = list(repl_tasks)
-        for (k, m, chunk, pattern), members in ec_groups.items():
+        for (k, m, rows, _, pattern), members in ec_groups.items():
             code = RSCode(k, m)
             _, datam = self._decode_shard_group(
                 code, [shards for _, _, shards in members], pattern)
@@ -682,8 +747,10 @@ class StorageCluster:
             if any(idx >= k for _, idx, _ in members):
                 parm = code.encode_stripes(datam)
             for s, (layout, idx, _) in enumerate(members):
-                rebuilt = datam[s, idx] if idx < k else parm[s, idx - k]
-                tasks.append((layout, idx, rebuilt))
+                cells = (datam[s * rows:(s + 1) * rows, idx] if idx < k
+                         else parm[s * rows:(s + 1) * rows, idx - k])
+                tasks.append((layout, idx,
+                              _shard_of(cells, layout.slot_lens[idx])))
         return stats, tasks
 
     @staticmethod
@@ -786,10 +853,11 @@ class StorageCluster:
                 out["lost_bytes"] += layout.size
                 continue
             if layout.resiliency == Resiliency.ERASURE_CODING:
-                coords = self._layout_coords(layout)
-                live = sum(c.node not in self.failed for c in coords)
-                data_live = all(
-                    c.node not in self.failed for c in layout.data_coords)
+                # an empty internal block is known zeros, on any node
+                up = [c.node not in self.failed or not length for c, length
+                      in zip(self._layout_coords(layout), layout.slot_lens)]
+                live = sum(up)
+                data_live = all(up[: layout.ec_k])
                 if data_live:
                     out["readable_bytes"] += layout.size
                 elif live >= layout.ec_k:

@@ -245,6 +245,37 @@ def join_stripe(chunks: np.ndarray, orig_size: int) -> bytes:
     return np.asarray(chunks, dtype=np.uint8).reshape(-1)[:orig_size].tobytes()
 
 
+def split_cells(blob: bytes | np.ndarray, k: int, cell: int,
+                align: int = 32) -> np.ndarray:
+    """HDFS's striped layout: the blob in cells of ``cell`` bytes, dealt
+    round-robin over k data blocks a row at a time, as (S, k, L) rows,
+    zero-padded.  L is ``cell`` when the blob fills a cell, else the
+    blob's length rounded up to ``align``: a short blob is one short
+    cell 0 and k - 1 empty cells.  Row ``r``'s cell ``j`` is then
+    ``out[r, j]``, and data block ``j`` is ``out[:, j]`` in row order,
+    cut to its :func:`internal_lengths` entry.  One whole row of k cells
+    is :func:`split_stripe`'s layout."""
+    if cell <= 0 or cell % align:
+        raise ValueError(f"cell of {cell} B is not a multiple of {align} B")
+    arr = np.frombuffer(bytes(blob), dtype=np.uint8) if isinstance(blob, (bytes, bytearray)) else np.asarray(blob, dtype=np.uint8).ravel()
+    rows = max(1, -(-arr.size // (k * cell)))
+    width = min(cell, -(-arr.size // align) * align)
+    out = np.zeros((rows, k, width), dtype=np.uint8)
+    out.reshape(-1)[: arr.size] = arr
+    return out
+
+
+def internal_lengths(size: int, k: int, m: int, cell: int) -> tuple[int, ...]:
+    """Bytes of each of the k + m internal blocks of a block group that
+    holds ``size`` bytes in cells of ``cell`` bytes (HDFS's
+    ``StripedBlockUtil.getInternalBlockLength``): a data block holds its
+    cell of every row, the last row's cells as far as the data reaches;
+    a parity block is as long as data block 0."""
+    full, last = divmod(size, k * cell)
+    data = [full * cell + min(cell, max(0, last - j * cell)) for j in range(k)]
+    return tuple(data + data[:1] * m)
+
+
 # ---------------------------------------------------------------------------
 # Streaming (per-packet) TriEC dataflow — the paper's contribution.
 # ---------------------------------------------------------------------------

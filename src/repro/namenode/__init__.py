@@ -15,7 +15,7 @@ handler stage (``HANDLER_NS["ns_*"]``) or a host-CPU RPC detour — see
 ``repro.policy`` and ``benchmarks/namespace.py``.
 """
 
-from .namespace import Block, DirNode, FileNode, Namespace
+from .namespace import Block, DirNode, ECPolicy, FileNode, Namespace
 from .namenode import NameNode
 from .placement import (
     FailureDomainPlacement,
@@ -29,6 +29,7 @@ __all__ = [
     "Block",
     "BlockReplicator",
     "DirNode",
+    "ECPolicy",
     "FailureDomainPlacement",
     "FileNode",
     "LoadBalancedPlacement",

@@ -15,18 +15,33 @@ does, out of parts this repo already has:
     through the existing :class:`repro.control.RepairPacer` token
     bucket, copying bytes via ``StorageCluster.re_replicate``.
 
+A file created under a directory with an erasure-coding policy
+(:meth:`NameNode.set_ec_policy`) is striped as HDFS stripes it: each
+block is a block group of k + m internal blocks on distinct datanodes,
+its bytes dealt in cells round-robin over the k data blocks, encoded
+on the cluster's batched codec (the chip's kernels on a TPU) and
+rebuilt from any k internal blocks when nodes are down.
+
 The facade also keeps per-op RPC counters (``lookups`` / ``opens`` /
 ``commits``) — the functional twin of the timed-plane metadata
 policies (``PolicySpec(op="lookup" | "open" | "commit")``), which cost
-those same RPCs in nanoseconds on a NIC handler or a host CPU.
+those same RPCs in nanoseconds on a NIC handler or a host CPU — and
+counts the block groups and internal blocks it wrote
+(``repro.trace.namenode_registry``).  Its RPCs open ``repro.trace.wall``
+spans in the ``metadata`` layer: ``nn.create``, ``nn.add_block``
+(allocation, layout and the write under it), ``nn.commit`` (extent map,
+length and generation stamp: HDFS's ``complete``) and ``nn.read``.
 """
 
 from __future__ import annotations
 
+import numpy as np
+
 from repro.membership.detector import MembershipConfig
 from repro.membership.view import View, ViewManager
+from repro.trace import wall
 
-from .namespace import Block, FileNode, Namespace
+from .namespace import Block, DirNode, ECPolicy, FileNode, Namespace
 from .placement import PlacementPolicy
 from .replicator import BlockReplicator
 
@@ -71,6 +86,11 @@ class NameNode:
         self.lookups = 0
         self.opens = 0
         self.commits = 0
+        # erasure-coded writes: block groups, and their internal blocks
+        # written and left empty
+        self.block_groups = 0
+        self.internal_blocks = 0
+        self.empty_internal_blocks = 0
 
     # -- metadata RPCs -------------------------------------------------------
 
@@ -86,36 +106,75 @@ class NameNode:
         self.opens += 1
         return self.namespace.mkdir(path)
 
-    def create(self, path: str, replication: int = 3) -> FileNode:
-        self.opens += 1
-        return self.namespace.create(path, replication)
+    def set_ec_policy(self, path: str, policy: ECPolicy | None) -> DirNode:
+        """HDFS's ``setErasureCodingPolicy``: files created under
+        directory ``path`` from now on are striped under ``policy``."""
+        return self.namespace.set_ec_policy(path, policy)
 
-    def add_block(self, path: str, data: bytes) -> Block:
-        """Append ``data`` as one replicated block of ``path``: place it
-        via the policy, write the replicas through the cluster's policy
-        engine, commit the extent-map entry (one open + one commit on
-        the RPC ledger — the lookup already happened at ``create``)."""
+    def create(self, path: str, replication: int = 3) -> FileNode:
+        with wall.span("nn.create", "metadata"):
+            self.opens += 1
+            return self.namespace.create(path, replication)
+
+    def add_block(self, path: str, data: bytes | np.ndarray) -> Block:
+        """Append ``data`` as one block of ``path`` and commit it (one
+        commit on the RPC ledger; the open happened at ``create``).
+
+        A replicated file's block is placed via the policy and its
+        replicas written through the cluster's policy engine.  An
+        erasure-coded file's block is a block group: k + m distinct live
+        datanodes; ``data`` dealt in the policy's cells over the k data
+        blocks, with HDFS's internal lengths; all its rows encoded in one
+        batched ``RSCode.encode_stripes`` call; and only its non-empty
+        internal blocks written."""
+        from repro.core.erasure import internal_lengths, split_cells
         from repro.core.packets import Resiliency
 
-        f = self.namespace.lookup(path)
-        if not isinstance(f, FileNode):
-            raise IsADirectoryError(path)
-        if self.cluster is None:
-            raise RuntimeError("clusterless NameNode cannot store bytes")
-        layout = self.cluster.write_object(
-            data, resiliency=Resiliency.REPLICATION, k=f.replication
-        )
-        self.commits += 1
-        blk = self.namespace.commit_block(
-            f, layout.size, [c.node for c in layout.data_coords],
-            object_id=layout.object_id,
-        )
-        self._layouts[layout.object_id] = layout
-        return blk
+        with wall.span("nn.add_block", "metadata"):
+            f = self.namespace.lookup(path)
+            if not isinstance(f, FileNode):
+                raise IsADirectoryError(path)
+            if self.cluster is None:
+                raise RuntimeError("clusterless NameNode cannot store bytes")
+            pol, lens = f.ec_policy, None
+            if pol is None:
+                layout = self.cluster.write_object(
+                    data, resiliency=Resiliency.REPLICATION, k=f.replication
+                )
+            else:
+                blob = np.frombuffer(bytes(data), np.uint8) if isinstance(
+                    data, (bytes, bytearray)) else np.asarray(data, np.uint8)
+                if not blob.size:
+                    raise ValueError("a block group holds at least one byte")
+                lens = internal_lengths(blob.size, pol.k, pol.m,
+                                        pol.cell_bytes)
+                layout = self.cluster.write_stripes(
+                    [split_cells(blob, pol.k, pol.cell_bytes)], [blob.size],
+                    pol.k, pol.m, [lens])[0]
+        with wall.span("nn.commit", "metadata"):
+            self.commits += 1
+            blk = self.namespace.commit_block(
+                f, layout.size,
+                [c.node for c in layout.data_coords + layout.parity_coords],
+                object_id=layout.object_id, internal_lens=lens,
+            )
+            self._layouts[layout.object_id] = layout
+            if lens is not None:
+                written = sum(1 for n in lens if n)
+                self.block_groups += 1
+                self.internal_blocks += written
+                self.empty_internal_blocks += len(lens) - written
+            return blk
 
     def read_block(self, block: Block) -> bytes:
-        self.lookups += 1
-        return self.cluster.read_object(self._layouts[block.object_id])
+        """A block's bytes.  A block group with internal blocks missing
+        is rebuilt from any k of them by ``RSCode.decode_stripes``, its
+        empty data blocks taken as zeros; like HDFS's striped reader it
+        does not re-encode to check the rebuilt bytes."""
+        with wall.span("nn.read", "metadata"):
+            self.lookups += 1
+            return self.cluster.read_object(self._layouts[block.object_id],
+                                            verify=False)
 
     # -- liveness (detected, never omniscient) -------------------------------
 

@@ -8,6 +8,12 @@ placement set changes (initial allocation, re-replication after a
 detected failure), which is what lets datanodes and clients fence stale
 replicas: a replica carrying an old stamp is garbage, not data.
 
+A directory may carry an erasure-coding policy (:class:`ECPolicy`, HDFS's
+``setErasureCodingPolicy``); a file created under it inherits the
+closest such policy, and each of its blocks is a *block group*: k + m
+internal blocks on distinct datanodes, each with its own length, under
+one generation stamp.
+
 This module is pure bookkeeping — no bytes, no IO, no liveness.  The
 :class:`~repro.namenode.NameNode` facade wires it to `StorageCluster`
 (bytes) and `repro.membership` (liveness).
@@ -18,18 +24,32 @@ from __future__ import annotations
 import dataclasses
 from collections.abc import Iterator
 
-__all__ = ["Block", "FileNode", "DirNode", "Namespace"]
+__all__ = ["Block", "ECPolicy", "FileNode", "DirNode", "Namespace"]
+
+
+@dataclasses.dataclass(frozen=True)
+class ECPolicy:
+    """An HDFS erasure-coding policy: RS(k, m) over cells of
+    ``cell_bytes``, dealt round-robin over the k data internal blocks of
+    a block group (``RS-6-3-1024k`` is ``ECPolicy(6, 3, 1 << 20)``)."""
+
+    k: int
+    m: int
+    cell_bytes: int
 
 
 @dataclasses.dataclass
 class Block:
-    """One fixed-position chunk of a file and where its replicas live."""
+    """One fixed-position chunk of a file and where its replicas live;
+    for an erasure-coded file a block group, whose ``placements`` hold
+    its k + m internal blocks in order, ``internal_lens`` long."""
 
     block_id: int
     size: int
     gen_stamp: int
     placements: list[int]               # datanode ids holding a replica
     object_id: int | None = None        # backing StorageCluster object
+    internal_lens: list[int] | None = None
 
     def replicas_on(self, nodes) -> int:
         return sum(1 for v in self.placements if v in nodes)
@@ -40,6 +60,7 @@ class FileNode:
     name: str
     replication: int
     blocks: list[Block] = dataclasses.field(default_factory=list)
+    ec_policy: ECPolicy | None = None
 
     @property
     def size(self) -> int:
@@ -50,6 +71,7 @@ class FileNode:
 class DirNode:
     name: str
     children: dict = dataclasses.field(default_factory=dict)
+    ec_policy: ECPolicy | None = None
 
 
 class Namespace:
@@ -98,15 +120,30 @@ class Namespace:
             node = child
         return node
 
+    def set_ec_policy(self, path: str, policy: ECPolicy | None) -> DirNode:
+        """Erasure-code the files created under directory ``path`` from
+        now on (None: inherit again); files already there keep their
+        layout."""
+        node = self.lookup(path)
+        if not isinstance(node, DirNode):
+            raise NotADirectoryError(path)
+        node.ec_policy = policy
+        return node
+
     def create(self, path: str, replication: int = 3) -> FileNode:
-        """The ``open``-for-write RPC: allocate an empty file inode."""
+        """The ``open``-for-write RPC: allocate an empty file inode, under
+        the erasure-coding policy of its closest directory that has one."""
         parts = self._parts(path)
         if not parts:
             raise ValueError("cannot create the root")
         parent = self._walk(parts[:-1])
         if parts[-1] in parent.children:
             raise FileExistsError(f"{path!r} already exists")
-        f = FileNode(parts[-1], replication)
+        policy, node = self.root.ec_policy, self.root
+        for p in parts[:-1]:
+            node = node.children[p]
+            policy = node.ec_policy or policy
+        f = FileNode(parts[-1], replication, ec_policy=policy)
         parent.children[parts[-1]] = f
         self.num_files += 1
         return f
@@ -149,13 +186,16 @@ class Namespace:
 
     def commit_block(self, file: FileNode, size: int,
                      placements: list[int],
-                     object_id: int | None = None) -> Block:
-        """The ``commit`` RPC: append a written block to a file's extent
-        map, stamped with a fresh generation number."""
+                     object_id: int | None = None,
+                     internal_lens: list[int] | None = None) -> Block:
+        """The ``commit`` RPC: append a written block (or block group, with
+        its ``internal_lens``) to a file's extent map, stamped with a
+        fresh generation number."""
         if size <= 0:
             raise ValueError(f"block size must be positive, got {size}")
         blk = Block(self._next_block_id, size, self.next_gen(),
-                    list(placements), object_id)
+                    list(placements), object_id,
+                    None if internal_lens is None else list(internal_lens))
         self._next_block_id += 1
         file.blocks.append(blk)
         return blk

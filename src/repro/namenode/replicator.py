@@ -53,7 +53,9 @@ class BlockReplicator:
         self.dead.update(nodes)
         added = 0
         for f, b in self.namespace.blocks():
-            if b.block_id in self._queued:
+            # a block group's lost internal blocks are rebuilt by decoding
+            # (StorageCluster.repair_node), not copied from a replica
+            if b.block_id in self._queued or b.internal_lens is not None:
                 continue
             if any(v in self.dead for v in b.placements):
                 self._queue.append((f, b))

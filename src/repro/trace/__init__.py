@@ -13,18 +13,21 @@ the served data plane:
 * :mod:`repro.trace.attr` — per-request / per-policy latency attribution
   into wire / hpu_queue / hpu_exec / pcie / host_cpu / client buckets
 * :mod:`repro.trace.wall` — wall-clock spans of the served data plane
-  (``span(name, layer)``, on while a ``Tracer.wall()`` is installed) and
-  :func:`dataplane_registry` over its always-on counters
+  (``span(name, layer)``, on while a ``Tracer.wall()`` is installed),
+  :func:`dataplane_registry` over its always-on counters and
+  :func:`namenode_registry` with the NameNode's
 """
 
 from .tracer import BUCKETS, Span, Tracer
-from .counters import CounterRegistry, dataplane_registry, registry_for
+from .counters import (CounterRegistry, dataplane_registry,
+                       namenode_registry, registry_for)
 from .perfetto import to_chrome_trace, write_chrome_trace
 from . import attr, wall
 
 __all__ = [
     "BUCKETS", "Span", "Tracer",
-    "CounterRegistry", "dataplane_registry", "registry_for",
+    "CounterRegistry", "dataplane_registry", "namenode_registry",
+    "registry_for",
     "to_chrome_trace", "write_chrome_trace",
     "attr", "wall",
 ]

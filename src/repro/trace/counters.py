@@ -19,7 +19,8 @@ tracks handler time and HPU-pool occupancy, every
 
 ``dataplane_registry(cluster)`` does the same for the served data plane
 (a :class:`~repro.checkpoint.storage.StorageCluster`): its always-on
-packet, capability, codec and per-node counters.
+packet, capability, codec and per-node counters;
+``namenode_registry(namenode)`` adds the NameNode's.
 
 ``registry_for(env, ...)`` wires a registry over an
 :class:`~repro.sim.protocols.Env` (network + PsPIN + serial resources +
@@ -203,4 +204,21 @@ def dataplane_registry(cluster, manager=None) -> CounterRegistry:
             "saves": len(manager.save_seconds),
             "failed_saves": manager.failed_saves,
         })
+    return reg
+
+
+def namenode_registry(namenode) -> CounterRegistry:
+    """:func:`dataplane_registry` of a :class:`~repro.namenode.NameNode`'s
+    cluster, with the NameNode's own counters:
+
+    * ``nn.lookups`` / ``.opens`` / ``.commits`` -- its RPC ledger;
+    * ``nn.block_groups`` -- erasure-coded blocks committed;
+    * ``nn.internal_blocks`` / ``.empty_internal_blocks`` -- their
+      internal blocks written, and those left empty (a short file's
+      unused data cells)."""
+    reg = dataplane_registry(namenode.cluster)
+    reg.register_group("nn", lambda: dict(
+        namenode.rpc_counts(), block_groups=namenode.block_groups,
+        internal_blocks=namenode.internal_blocks,
+        empty_internal_blocks=namenode.empty_internal_blocks))
     return reg

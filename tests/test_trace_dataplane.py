@@ -303,3 +303,36 @@ def test_node_counts_replace_the_event_list():
     held = [c.node for c in list(layout.data_coords)
             + list(layout.parity_coords)]
     assert sum(cluster.nodes[n].counts["write_done"] for n in held) == 5
+
+
+def test_namenode_spans_and_counters_of_one_create_write_close(tracer):
+    """One 3,901-byte file in an RS-6-3-1024k directory: the NameNode's
+    spans in the order of its RPCs, the write under ``nn.add_block``, and
+    its counters moved by one open, one commit, one block group, 4
+    internal blocks written and 5 left empty."""
+    from repro.namenode import ECPolicy, NameNode
+    from repro.trace import namenode_registry
+
+    cluster = StorageCluster(num_nodes=12, node_capacity=1 << 20)
+    nn = NameNode(cluster)
+    nn.mkdir("/mdtest-hard")
+    nn.set_ec_policy("/mdtest-hard", ECPolicy(6, 3, 1 << 20))
+    reg = namenode_registry(nn)
+    before = reg.snapshot()
+    start = len(tracer.spans)
+    nn.create("/mdtest-hard/file.mdtest.0.0")
+    nn.add_block("/mdtest-hard/file.mdtest.0.0", bytes(3901))
+    spans = tracer.spans[start:]
+    nn_spans = [s for s in spans if s.name.startswith("nn.")]
+    assert [s.name for s in nn_spans] == ["nn.create", "nn.add_block",
+                                         "nn.commit"]
+    assert [s.t0 for s in nn_spans] == sorted(s.t0 for s in nn_spans)
+    assert all(s.cat == "metadata" for s in nn_spans)
+    (write,) = _by_name(tracer, "cluster.write")
+    assert write.parent is nn_spans[1]
+    moved = {n: v for n, v in reg.diff(before, reg.snapshot()).items()
+             if v and n.startswith("nn.")}
+    assert moved == {"nn.opens": 1, "nn.commits": 1, "nn.block_groups": 1,
+                     "nn.internal_blocks": 4, "nn.empty_internal_blocks": 5}
+    # the packet plane carried the 4 internal blocks and nothing else
+    assert reg.diff(before, reg.snapshot())["node.write_done"] == 4
