@@ -38,10 +38,12 @@ def _interpret() -> bool:
 #: The codec's transfers and dispatches since the process started, always
 #: on: read them as differences between two snapshots.  ``dispatches`` /
 #: ``stripes`` count :func:`gf_matmul_bytes_batched`'s fused dispatches
-#: and the stripes they carried, ``h2d_bytes`` the host stripe bytes it
-#: moved to the device, ``d2h_bytes`` what :func:`to_host` brought back.
-CODEC_COUNTS = dict.fromkeys(("dispatches", "stripes", "h2d_bytes",
-                              "d2h_bytes"), 0)
+#: and the stripes they carried, ``padded`` the dispatches whose chunk
+#: length the program padded to its tile, ``h2d_bytes`` the host stripe
+#: bytes it moved to the device, ``d2h_bytes`` what :func:`to_host`
+#: brought back.
+CODEC_COUNTS = dict.fromkeys(("dispatches", "stripes", "padded",
+                              "h2d_bytes", "d2h_bytes"), 0)
 
 
 def to_host(x: jax.Array) -> np.ndarray:
@@ -109,18 +111,22 @@ def _pick_block_w(length: int, block_w: int | None) -> int:
 
 @functools.partial(jax.jit, static_argnames=("block_w", "interpret"))
 def _encode_planes_batched(bitmat, data_bytes, block_w, interpret):
-    """Fused pipeline under one jit: bit-plane pack -> single batched Pallas
-    dispatch over the (stripe, word-block) grid -> unpack.  The pack and
-    unpack ops carry the ``rs_pack`` / ``rs_unpack`` scopes in their op
-    names, the kernel is ``rs_gf_matmul``."""
+    """Fused pipeline under one jit: pad the chunk length L to the kernel
+    tile -> bit-plane pack -> single batched Pallas dispatch over the
+    (stripe, word-block) grid -> unpack -> trim back to L, so a dispatch
+    is one device program whatever L is.  The pad and pack ops carry the
+    ``rs_pack`` scope in their op names, the unpack and trim ``rs_unpack``,
+    the kernel is ``rs_gf_matmul``."""
+    length = data_bytes.shape[2]
     with jax.named_scope("rs_pack"):
+        data_bytes, _ = _pad_to(data_bytes, 32 * block_w, axis=2)
         planes = ref.pack_bitplanes(data_bytes)      # (S, k, 8, w)
     m, k = bitmat.shape[0], bitmat.shape[1]
     out_planes = gf_matmul_bitsliced_batched(
         bitmat, planes, m=m, k=k, block_w=block_w, interpret=interpret
     )
     with jax.named_scope("rs_unpack"):
-        return ref.unpack_bitplanes(out_planes)      # (S, m, L)
+        return ref.unpack_bitplanes(out_planes)[:, :, :length]  # (S, m, L)
 
 
 #: Payload bytes, (k + n) * L per stripe, that one fused dispatch may
@@ -172,6 +178,7 @@ def gf_matmul_bytes_batched(
         return ref.gf_matmul_batched_ref(
             jnp.asarray(coeffs_np), jnp.asarray(data, dtype=jnp.uint8))
     bw = _pick_block_w(length, block_w)
+    padded = length % (32 * bw) != 0
     bitmat = _bitmat_device(coeffs_np.tobytes(), n, k)
     outs, lo = [], 0
     for size in _dispatch_sizes(s, (k + n) * length):
@@ -181,12 +188,10 @@ def gf_matmul_bytes_batched(
         with wall.span("codec.h2d", "coding"):
             piece = jnp.asarray(piece, dtype=jnp.uint8)
         with wall.span("codec.launch", "coding"):
-            # Pad L so the packed word count divides the kernel block.
-            piece, _ = _pad_to(piece, 32 * bw, axis=2)
-            out = _encode_planes_batched(bitmat, piece, bw, _interpret())
-            outs.append(out[:, :, :length])
+            outs.append(_encode_planes_batched(bitmat, piece, bw, _interpret()))
         CODEC_COUNTS["dispatches"] += 1
         CODEC_COUNTS["stripes"] += size
+        CODEC_COUNTS["padded"] += padded
         lo += size
     return outs[0] if len(outs) == 1 else jnp.concatenate(outs)
 
@@ -197,9 +202,8 @@ def gf_matmul_program(n: int, k: int, s: int, length: int, sharding=None):
     lowered for this platform (or for ``sharding``'s device) but not run:
     its text names ``tpu_custom_call`` when the kernel compiles natively."""
     bw = _pick_block_w(length, None)
-    padded = -(-length // (32 * bw)) * 32 * bw
     bitmat = jax.ShapeDtypeStruct((n, k, 8, 8), jnp.uint32, sharding=sharding)
-    data = jax.ShapeDtypeStruct((s, k, padded), jnp.uint8, sharding=sharding)
+    data = jax.ShapeDtypeStruct((s, k, length), jnp.uint8, sharding=sharding)
     return _encode_planes_batched.lower(bitmat, data, bw, _interpret())
 
 

@@ -170,8 +170,9 @@ def dataplane_registry(cluster, manager=None) -> CounterRegistry:
       router's deliveries to storage nodes, to client inboxes (acks,
       NACKs, read responses) and its drops;
     * ``auth.verifications`` -- capability checks by the header handlers;
-    * ``codec.dispatches`` / ``.stripes`` / ``.h2d_bytes`` /
-      ``.d2h_bytes`` -- the process's fused codec dispatches and transfers
+    * ``codec.dispatches`` / ``.stripes`` / ``.padded`` / ``.h2d_bytes``
+      / ``.d2h_bytes`` -- the process's fused codec dispatches, those
+      whose chunk length the program padded to its tile, and transfers
       (``repro.kernels.ops.CODEC_COUNTS``, shared by every cluster);
     * ``node.<kind>`` -- the nodes' handler events by kind, summed;
     * ``ckpt.saves`` / ``.failed_saves`` with ``manager``.

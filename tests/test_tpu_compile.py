@@ -61,6 +61,16 @@ def test_batched_encode_program_compiles(one_chip, k, m):
     assert _has_kernel(ops.gf_matmul_program(m, k, s, 4096, sharding=one_chip))
 
 
+@pytest.mark.parametrize("length", [3904, 341_334], ids=["mdtest", "save"])
+def test_off_tile_chunk_program_compiles(one_chip, length):
+    """RS(6,3) encode of a chunk off the kernel tile (an mdtest-hard
+    file's 3,904-byte chunk, a checkpoint leaf's 341 kB one): the pad and
+    the trim compile inside the one program the dispatch runs."""
+    assert length % (32 * ops._pick_block_w(length, None))
+    assert _has_kernel(ops.gf_matmul_program(3, 6, 1, length,
+                                             sharding=one_chip))
+
+
 @pytest.mark.parametrize("n,k", [(3, 6), (6, 6)], ids=["encode", "decode"])
 def test_rs63_kernel_compiles_at_1mib_cell(one_chip, n, k):
     """RS(6,3) encode and the 6x6 decode at HDFS's 1 MiB cell, with the

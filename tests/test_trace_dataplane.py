@@ -250,6 +250,8 @@ def test_counters_count_what_a_write_must_send():
     assert d["auth.verifications"] == k + m
     assert d["node.write_done"] == k + m
     assert (d["codec.dispatches"], d["codec.stripes"]) == (1, 1)
+    # 20,000 B is off the tile: the program pads it
+    assert d["codec.padded"] == 1
     assert d["codec.h2d_bytes"] == k * length
     assert d["codec.d2h_bytes"] == m * length
     cluster.fail_node(layout.data_coords[0].node)
@@ -264,6 +266,7 @@ def test_counters_count_what_a_write_must_send():
     assert d["node.read_done"] == k + m - 1
     # decode, then the verify re-encode: two S = 1 dispatches
     assert (d["codec.dispatches"], d["codec.stripes"]) == (2, 2)
+    assert d["codec.padded"] == 2
 
 
 def test_codec_counts_are_process_wide():
@@ -273,6 +276,7 @@ def test_codec_counts_are_process_wide():
     # S = 3 goes as power-of-two pieces: 2 stripes, then 1
     assert ops.CODEC_COUNTS["dispatches"] == before["dispatches"] + 2
     assert ops.CODEC_COUNTS["stripes"] == before["stripes"] + 3
+    assert ops.CODEC_COUNTS["padded"] == before["padded"] + 2
     assert ops.CODEC_COUNTS["h2d_bytes"] == before["h2d_bytes"] + data.nbytes
     assert ops.CODEC_COUNTS["d2h_bytes"] == before["d2h_bytes"] + 3 * 64
 
@@ -289,6 +293,18 @@ def test_codec_program_carries_pack_and_unpack_scopes():
     text = ops.gf_matmul_program(3, 6, 1, 4096).as_text(debug_info=True)
     assert "_encode_planes_batched)/rs_pack/" in text
     assert "_encode_planes_batched)/rs_unpack/" in text
+    assert "rs_gf_matmul" in text
+
+
+def test_codec_program_lowers_the_unpadded_piece():
+    """An off-tile chunk lowers as the dispatch sends it, (S, k, L) in
+    and (S, n, L) out, with the pad and the trim inside the program."""
+    lowered = ops.gf_matmul_program(3, 6, 2, 3904)
+    assert [a.shape for a in lowered.in_avals[0]] == [(3, 6, 8, 8),
+                                                      (2, 6, 3904)]
+    assert lowered.out_info.shape == (2, 3, 3904)
+    text = lowered.as_text(debug_info=True)
+    assert "_encode_planes_batched)/rs_pack/" in text
     assert "rs_gf_matmul" in text
 
 
