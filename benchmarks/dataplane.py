@@ -4,13 +4,14 @@ Sweeps stripe count x chunk size x RS scheme over the bit-sliced kernel
 data plane (kernels/ops.py) and emits ``BENCH_dataplane.json`` — the
 bytes/s trajectory every future PR regresses against.  Two paths per cell:
 
-  * ``per_stripe``: S separate ``ops.rs_encode`` calls — one dispatch,
-    pack/unpack round trip, and host sync per stripe.  Runs with the same
-    adaptive tile size as the batched path (``block_w=None``), so the
-    ratio isolates batching itself, not tile-padding differences;
-  * ``batched``: one ``ops.rs_encode_stripes`` call (single fused
-    pack -> bit-sliced matmul -> unpack dispatch over the whole
-    (stripe, word-block) grid).
+  * ``per_stripe``: S separate ``ops.gf_matmul_bytes_batched`` calls of
+    one stripe each — one dispatch, pack/unpack round trip, and host sync
+    per stripe.  The tile is chosen from the chunk length alone, the same
+    as the batched path's, so the ratio isolates batching itself, not
+    tile-padding differences;
+  * ``batched``: one ``ops.gf_matmul_bytes_batched`` call over all S
+    stripes (single fused pack -> bit-sliced matmul -> unpack dispatch
+    over the whole (stripe, word-block) grid).
 
 Throughput counts data bytes in (S * k * L) per encode.  On CPU the Pallas
 kernel runs in interpret mode, so absolute numbers track the pipeline
@@ -60,22 +61,25 @@ def sweep(
     chunk_sizes=DEFAULT_CHUNKS,
     repeats: int = 3,
 ) -> dict:
+    from repro.core.erasure import RSCode
     from repro.kernels import ops
 
     rng = np.random.default_rng(0)
     rows = []
     for k, m in codes:
+        parity = RSCode(k, m).parity_matrix
         for chunk in chunk_sizes:
             for s in stripes:
                 data = rng.integers(0, 256, (s, k, chunk), dtype=np.uint8)
                 nbytes = data.nbytes
 
-                def per_stripe(data=data, k=k, m=m):
-                    for stripe in data:
-                        np.asarray(ops.rs_encode(stripe, k, m, block_w=None))
+                def per_stripe(data=data, parity=parity):
+                    for i in range(len(data)):
+                        np.asarray(ops.gf_matmul_bytes_batched(
+                            parity, data[i:i + 1]))
 
-                def batched(data=data, k=k, m=m):
-                    np.asarray(ops.rs_encode_stripes(data, k, m))
+                def batched(data=data, parity=parity):
+                    np.asarray(ops.gf_matmul_bytes_batched(parity, data))
 
                 t_loop = _time(per_stripe, repeats)
                 t_batch = _time(batched, repeats)

@@ -218,12 +218,13 @@ def bench_kernels_throughput() -> list[tuple]:
         # per-stripe loop vs one fused batched dispatch (both with the
         # adaptive tile, so the ratio isolates batching).
         batch = rng.integers(0, 256, (8, k, 4096), dtype=np.uint8)
+        parity = code.parity_matrix
         for name, fn in [
-            ("loop", lambda b=batch: [np.asarray(ops.rs_encode(s, k, m,
-                                                               block_w=None))
-                                      for s in b]),
+            ("loop", lambda b=batch: [np.asarray(
+                ops.gf_matmul_bytes_batched(parity, b[i:i + 1]))
+                for i in range(len(b))]),
             ("batched", lambda b=batch: np.asarray(
-                ops.rs_encode_stripes(b, k, m))),
+                ops.gf_matmul_bytes_batched(parity, b))),
         ]:
             fn()  # warmup (jit trace)
             t0 = time.perf_counter()

@@ -297,7 +297,6 @@ class StorageCluster:
         blobs: list[bytes | np.ndarray],
         k: int = 4,
         m: int = 2,
-        backend: str | None = None,
     ) -> list[ObjectLayout]:
         """Batched client-side EC — the ``RS(engine='client')`` plan: each
         blob is one row of k equal cells
@@ -313,8 +312,7 @@ class StorageCluster:
                 for b in blobs
             ]
             return self._write_stripes([split_stripe(a, k)[None] for a in arrs],
-                                       [a.size for a in arrs], k, m, None,
-                                       backend)
+                                       [a.size for a in arrs], k, m, None)
 
     @_io_locked
     def write_stripes(
@@ -324,7 +322,6 @@ class StorageCluster:
         k: int,
         m: int,
         slot_lens: list[tuple[int, ...]] | None = None,
-        backend: str | None = None,
     ) -> list[ObjectLayout]:
         """Encode and write objects already split into rows of cells.
 
@@ -332,16 +329,15 @@ class StorageCluster:
         data cells; its shard ``j`` is its cell of every row, cut to
         ``slot_lens[i][j]`` bytes (default: whole), and an empty shard
         takes no extent and no write.  All rows of one width are encoded
-        in *one* ``RSCode.encode_stripes`` call (backend="jax" is a
-        single fused kernel dispatch per piece; None takes the platform's
-        data-plane default, the kernels on a TPU), then every non-empty
-        data/parity shard is written as an authenticated plain write
-        through the policy engine."""
+        in *one* ``RSCode.encode_stripes`` call (on a TPU one fused
+        kernel dispatch per piece), then every non-empty data/parity
+        shard is written as an authenticated plain write through the
+        policy engine."""
         with wall.span("cluster.write", "entry"):
-            return self._write_stripes(rows, sizes, k, m, slot_lens, backend)
+            return self._write_stripes(rows, sizes, k, m, slot_lens)
 
-    def _write_stripes(self, rows, sizes, k: int, m: int, slot_lens,
-                       backend: str | None) -> list[ObjectLayout]:
+    def _write_stripes(self, rows, sizes, k: int, m: int,
+                       slot_lens) -> list[ObjectLayout]:
         from repro.core.erasure import RSCode
 
         lens = slot_lens or [None] * len(rows)
@@ -365,7 +361,7 @@ class StorageCluster:
                     parities[i] = np.zeros((rows[i].shape[0], m, 0), np.uint8)
                 continue
             batch = np.concatenate([rows[i] for i in idxs])    # (S, k, L)
-            par = code.encode_stripes(batch, backend=backend)  # (S, m, L)
+            par = code.encode_stripes(batch)  # (S, m, L)
             lo = 0
             for i in idxs:
                 parities[i] = par[lo:lo + rows[i].shape[0]]
@@ -455,7 +451,6 @@ class StorageCluster:
         self,
         layouts: list[ObjectLayout],
         verify: bool = True,
-        backend: str | None = None,
     ) -> list[bytes]:
         """Batched degraded-capable read through the packet plane.
 
@@ -471,10 +466,10 @@ class StorageCluster:
         first surviving replica.
         """
         with wall.span("cluster.read", "entry"):
-            return self._read_objects(layouts, verify, backend)
+            return self._read_objects(layouts, verify)
 
-    def _read_objects(self, layouts: list[ObjectLayout], verify: bool,
-                      backend: str | None) -> list[bytes]:
+    def _read_objects(self, layouts: list[ObjectLayout],
+                      verify: bool) -> list[bytes]:
         from repro.core.erasure import RSCode
 
         out: list[bytes | None] = [None] * len(layouts)
@@ -521,7 +516,7 @@ class StorageCluster:
             # one batched decode per (geometry, chunk, erasure pattern)
             try:
                 batched, datam = self._decode_shard_group(
-                    code, [shards for _, shards in members], pattern, backend)
+                    code, [shards for _, shards in members], pattern)
             except ValueError as exc:
                 # normalize to the method's failure contract (IOError),
                 # like every other unreadable-object path
@@ -530,7 +525,7 @@ class StorageCluster:
             if verify and pattern:
                 # recovered stripes must re-encode bit-exact to every
                 # surviving parity shard (the encode layout is the truth)
-                par = code.encode_stripes(datam, backend=backend)
+                par = code.encode_stripes(datam)
                 for pi in range(m):
                     slot = k + pi
                     if slot in pattern:
@@ -629,7 +624,7 @@ class StorageCluster:
             layout.parity_coords[idx - len(layout.data_coords)] = coord
 
     @staticmethod
-    def _decode_shard_group(code, shard_lists, pattern, backend=None):
+    def _decode_shard_group(code, shard_lists, pattern):
         """Stack each slot's per-member (rows, L) shards into an (S, L)
         batch and reconstruct the whole (geometry, chunk, erasure-pattern)
         group in ONE ``decode_stripes`` call.  Returns (batched_slots,
@@ -639,7 +634,7 @@ class StorageCluster:
             else np.concatenate([shards[i] for shards in shard_lists])
             for i in range(code.n)
         ]
-        return batched, code.decode_stripes(batched, backend=backend)
+        return batched, code.decode_stripes(batched)
 
     def _repair(self, node_id: int, replacement: int | None,
                 pacer=None) -> dict:

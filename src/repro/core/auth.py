@@ -205,8 +205,7 @@ class CapabilityAuthority:
     """Control-plane issuer/verifier holding the DFS-shared key.
 
     The metadata service owns an instance and signs tickets; storage-node
-    header handlers hold the key and verify (``verify`` is the host path,
-    ``repro.kernels.ops.bulk_verify`` the jitted batch path).
+    header handlers hold the key and verify (``verify``, on the host).
     """
 
     def __init__(self, key: bytes | np.ndarray):

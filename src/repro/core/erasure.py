@@ -7,8 +7,9 @@ encoding: intermediate parities are computed per network packet at the data
 nodes and XOR-aggregated at the parity nodes, instead of waiting for whole
 chunks (INEC-TriEC) — see :class:`TriECDataNode` / :class:`TriECParityNode`.
 
-The bulk math is delegated to ``repro.kernels.ops`` which dispatches between
-the bit-sliced Pallas TPU kernel and the jnp reference path; this module adds
+The bulk math is one GF(2^8) matmul (:func:`_matmul`): the host LUT path,
+or ``repro.kernels.ops.gf_matmul_bytes_batched``'s bit-sliced Pallas kernel
+on a TPU (``ops.dataplane_backend`` chooses); this module adds
 the coding-theory layer (generator matrices, decode solvers, chunking) and
 the per-packet dataflow objects used by both the functional DFS node
 (core/handlers.py) and the cycle-approximate simulator (sim/).
@@ -29,8 +30,10 @@ from repro.trace import wall
 class RSCode:
     """A systematic RS(k, m) code over GF(2^8).
 
-    ``encode`` / ``decode`` operate on byte matrices of shape (k, chunk_len):
-    row ``j`` is data chunk ``j``.  All chunks of one stripe share a length.
+    ``encode_stripes`` / ``decode_stripes`` operate on (S, k, chunk_len)
+    stripe batches, ``encode`` / ``decode`` on one stripe's (k, chunk_len)
+    byte matrix: row ``j`` is data chunk ``j``.  All chunks of one stripe
+    share a length.
     """
 
     k: int
@@ -62,133 +65,67 @@ class RSCode:
 
     # -- whole-stripe paths ------------------------------------------------
 
-    def encode(self, data: np.ndarray, backend: str | None = None) -> np.ndarray:
-        """(k, L) data bytes -> (m, L) parity bytes.
-
-        backend="numpy" uses the host LUT path (the paper's per-byte table
-        walk, vectorized); backend="jax" routes through kernels/ops.py
-        (bit-sliced, Pallas on TPU / interpret on CPU); None takes the
-        platform's data-plane default (:func:`_backend`).
-        """
-        backend = _backend(backend)
-        data = np.asarray(data, dtype=np.uint8)
-        if data.shape[0] != self.k:
-            raise ValueError(f"expected {self.k} data chunks, got {data.shape[0]}")
-        if self.m == 0:
-            return np.zeros((0, data.shape[1]), dtype=np.uint8)
-        if backend == "numpy":
-            return gf256.gf_matmul(self.parity_matrix, data)
-        if backend == "jax":
-            from repro.kernels import ops
-
-            return np.asarray(
-                ops.rs_encode_stripes(
-                    data[None], self.k, self.m, kind=self.kind
-                )[0]
-            )
-        raise ValueError(f"unknown backend {backend!r}")
-
     def encode_stripes(
         self, data: np.ndarray, backend: str | None = None
     ) -> np.ndarray:
-        """Batched encode: (S, k, L) data -> (S, m, L) parity.
-
-        backend="jax" is one fused kernel dispatch for the whole batch
-        (kernels/ops.py); backend="numpy" is the vectorized host LUT path.
-        """
+        """Batched encode: (S, k, L) data -> (S, m, L) parity."""
         with wall.span("rs.encode", "coding"):
-            return self._encode_stripes(data, _backend(backend))
-
-    def _encode_stripes(self, data: np.ndarray, backend: str) -> np.ndarray:
-        data = np.asarray(data, dtype=np.uint8)
-        if data.ndim != 3 or data.shape[1] != self.k:
-            raise ValueError(f"expected (S, {self.k}, L) stripes, got {data.shape}")
-        s, _, length = data.shape
-        if self.m == 0:
-            return np.zeros((s, 0, length), dtype=np.uint8)
-        if backend == "numpy":
-            flat = data.transpose(1, 0, 2).reshape(self.k, s * length)
-            out = gf256.gf_matmul(self.parity_matrix, flat)
-            return out.reshape(self.m, s, length).transpose(1, 0, 2)
-        if backend == "jax":
-            from repro.kernels import ops
-
-            return ops.to_host(
-                ops.rs_encode_stripes(data, self.k, self.m, kind=self.kind)
-            )
-        raise ValueError(f"unknown backend {backend!r}")
-
-    def decode(
-        self,
-        shards: Sequence[np.ndarray | None],
-        backend: str | None = None,
-    ) -> np.ndarray:
-        """Reconstruct (k, L) data from any >= k surviving shards.
-
-        ``shards`` has length k+m; missing shards are None.  Shard ``i < k``
-        is data chunk ``i``; shard ``k + i`` is parity row ``i``.
-        """
-        if len(shards) != self.n:
-            raise ValueError(f"expected {self.n} shard slots, got {len(shards)}")
-        present = [i for i, s in enumerate(shards) if s is not None]
-        if len(present) < self.k:
-            raise ValueError(
-                f"unrecoverable: only {len(present)} of >= {self.k} shards present"
-            )
-        missing_data = [i for i in range(self.k) if shards[i] is None]
-        if not missing_data:
-            return np.stack([np.asarray(shards[i], dtype=np.uint8) for i in range(self.k)])
-        rows = present[: self.k]
-        sub = self.generator[rows]  # (k, k) — invertible because MDS
-        inv = gf256.gf_mat_inv(sub)
-        stacked = np.stack([np.asarray(shards[i], dtype=np.uint8) for i in rows])
-        if _backend(backend) == "jax":
-            from repro.kernels import ops
-
-            return np.asarray(ops.gf_matmul_bytes(inv, stacked, block_w=None))
-        return gf256.gf_matmul(inv, stacked)
+            data = np.asarray(data, dtype=np.uint8)
+            if data.ndim != 3 or data.shape[1] != self.k:
+                raise ValueError(
+                    f"expected (S, {self.k}, L) stripes, got {data.shape}")
+            if self.m == 0:
+                return np.zeros((data.shape[0], 0, data.shape[2]), np.uint8)
+            return _matmul(self.parity_matrix, data, backend)
 
     def decode_stripes(
         self,
         shards: Sequence[np.ndarray | None],
         backend: str | None = None,
     ) -> np.ndarray:
-        """Batched decode: reconstruct (S, k, L) data from surviving shards.
+        """Batched decode: reconstruct (S, k, L) data from any >= k
+        surviving shards.
 
-        ``shards`` has length k+m like :meth:`decode`, but each present
-        entry is a (S, L) batch (the same erasure pattern applies to every
-        stripe — the common whole-node-failure case).  One fused kernel
-        dispatch recovers all S stripes.
+        ``shards`` has length k+m; missing shards are None, each present
+        one a (S, L) batch.  Shard ``i < k`` is data chunk ``i``; shard
+        ``k + i`` is parity row ``i``.  The same erasure pattern applies
+        to every stripe (the common whole-node-failure case), so one
+        matmul recovers all S stripes.
         """
         with wall.span("rs.decode", "coding"):
-            return self._decode_stripes(shards, backend)
+            if len(shards) != self.n:
+                raise ValueError(
+                    f"expected {self.n} shard slots, got {len(shards)}")
+            present = [i for i, s in enumerate(shards) if s is not None]
+            if len(present) < self.k:
+                raise ValueError(
+                    f"unrecoverable: only {len(present)} of >= {self.k} "
+                    f"shards present")
+            rows = present[: self.k]
+            stacked = np.stack(
+                [np.asarray(shards[i], dtype=np.uint8) for i in rows], axis=1
+            )  # (S, k, L)
+            if rows == list(range(self.k)):
+                return stacked
+            # the (k, k) generator rows are invertible because RS is MDS
+            inv = gf256.gf_mat_inv(self.generator[rows])
+            return _matmul(inv, stacked, backend)
 
-    def _decode_stripes(self, shards, backend: str | None) -> np.ndarray:
-        if len(shards) != self.n:
-            raise ValueError(f"expected {self.n} shard slots, got {len(shards)}")
-        present = [i for i, s in enumerate(shards) if s is not None]
-        if len(present) < self.k:
-            raise ValueError(
-                f"unrecoverable: only {len(present)} of >= {self.k} shards present"
-            )
-        missing_data = [i for i in range(self.k) if shards[i] is None]
-        if not missing_data:
-            return np.stack(
-                [np.asarray(shards[i], dtype=np.uint8) for i in range(self.k)], axis=1
-            )
-        rows = present[: self.k]
-        inv = gf256.gf_mat_inv(self.generator[rows])
-        stacked = np.stack(
-            [np.asarray(shards[i], dtype=np.uint8) for i in rows], axis=1
-        )  # (S, k, L)
-        if _backend(backend) == "jax":
-            from repro.kernels import ops
+    # -- one-stripe views ----------------------------------------------------
 
-            return ops.to_host(ops.gf_matmul_bytes_batched(inv, stacked))
-        s, _, length = stacked.shape
-        flat = stacked.transpose(1, 0, 2).reshape(self.k, s * length)
-        out = gf256.gf_matmul(inv, flat)
-        return out.reshape(self.k, s, length).transpose(1, 0, 2)
+    def encode(self, data: np.ndarray, backend: str | None = None) -> np.ndarray:
+        """(k, L) data bytes -> (m, L) parity bytes."""
+        return self.encode_stripes(np.asarray(data)[None], backend)[0]
+
+    def decode(
+        self,
+        shards: Sequence[np.ndarray | None],
+        backend: str | None = None,
+    ) -> np.ndarray:
+        """Reconstruct (k, L) data from any >= k of the k+m ``shards``."""
+        return self.decode_stripes(
+            [None if s is None else np.asarray(s)[None] for s in shards],
+            backend)[0]
 
     def reconstruct_shard(
         self, shards: Sequence[np.ndarray | None], index: int
@@ -197,16 +134,34 @@ class RSCode:
         data = self.decode(shards)
         if index < self.k:
             return data[index]
-        return gf256.gf_matmul(self.parity_matrix[index - self.k : index - self.k + 1], data)[0]
+        return self.encode(data)[index - self.k]
 
 
 def _backend(backend: str | None) -> str:
     """The caller's backend, else the platform's data-plane default: the
-    Pallas kernels on a TPU, the numpy LUT path elsewhere
-    (``repro.kernels.ops.dataplane_backend``)."""
+    Pallas kernels (``"jax"``) on a TPU, the numpy LUT path elsewhere
+    (``repro.kernels.ops.dataplane_backend``, asked at call time)."""
     from repro.kernels.ops import dataplane_backend
 
     return dataplane_backend(backend)
+
+
+def _matmul(coeffs: np.ndarray, stacked: np.ndarray,
+            backend: str | None) -> np.ndarray:
+    """(n, k) GF coefficients x (S, k, L) stripes -> (S, n, L) on the
+    host, by :func:`_backend`: the numpy LUT path over the (k, S*L)
+    flattening, or one ``ops.gf_matmul_bytes_batched`` call."""
+    from repro.kernels import ops
+
+    backend = _backend(backend)
+    if backend == "numpy":
+        s, k, length = stacked.shape
+        flat = stacked.transpose(1, 0, 2).reshape(k, s * length)
+        out = gf256.gf_matmul(coeffs, flat)
+        return out.reshape(len(coeffs), s, length).transpose(1, 0, 2)
+    if backend == "jax":
+        return ops.to_host(ops.gf_matmul_bytes_batched(coeffs, stacked))
+    raise ValueError(f"unknown backend {backend!r}")
 
 
 _PARITY_CACHE: dict[tuple[int, int, str], np.ndarray] = {}

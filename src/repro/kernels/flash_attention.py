@@ -17,7 +17,7 @@ Block shapes default to (128, head_dim) q-tiles x (512, head_dim) kv-tiles
 interpret mode against the jnp reference across shape sweeps
 (tests/test_kernels.py); the jnp blockwise path in models/attention.py is
 the CPU/backward implementation, this kernel is the TPU-forward
-replacement (`ops.flash_attention` dispatches on backend).
+replacement.
 """
 
 from __future__ import annotations

@@ -164,56 +164,3 @@ def gf_scale_bitsliced(
         interpret=interpret,
     )(_masks(bitmat), planes)
     return out.reshape(k, m, 8, w).transpose(1, 0, 2, 3)
-
-
-# ---------------------------------------------------------------------------
-# MXU variant: GF(2) matmul as int8 dot + parity (beyond-paper experiment).
-# ---------------------------------------------------------------------------
-
-
-def _gf_mxu_kernel(bigmat_ref, bits_ref, out_ref):
-    """(8m, 8k) GF(2) matrix x (8k, block_n) bit columns -> (8m, block_n).
-
-    GF(2) matmul == integer matmul followed by mod-2: routes the XOR
-    accumulation through the MXU instead of the VPU.  Operands are int8
-    bits; accumulation in int32 (max k*8 = 2048 < 2^31 safe).
-    """
-    acc = jnp.dot(
-        bigmat_ref[...].astype(jnp.int8),
-        bits_ref[...].astype(jnp.int8),
-        preferred_element_type=jnp.int32,
-    )
-    out_ref[...] = (acc & 1).astype(jnp.int8)
-
-
-@functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
-def gf_matmul_mxu(
-    bigmat: jax.Array,
-    bits: jax.Array,
-    *,
-    block_n: int = 512,
-    interpret: bool,
-) -> jax.Array:
-    """MXU-path GF(2) matmul: (8m, 8k) x (8k, n) -> (8m, n) over bits.
-
-    ``bigmat`` is the block bit-matrix (rows = output bits, cols = input
-    bits); ``bits`` holds one input bit per int8 element (unpacked).  The
-    bit-unpack/pack happens outside (ops.py) — the kernel is pure matmul
-    so XLA maps it onto the systolic array.
-    """
-    em, ek = bigmat.shape
-    ek2, n = bits.shape
-    assert ek == ek2, (bigmat.shape, bits.shape)
-    assert n % block_n == 0, (n, block_n)
-    grid = (n // block_n,)
-    return pl.pallas_call(
-        _gf_mxu_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((em, ek), lambda i: (0, 0)),
-            pl.BlockSpec((ek, block_n), lambda i: (0, i)),
-        ],
-        out_specs=pl.BlockSpec((em, block_n), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((em, n), jnp.int8),
-        interpret=interpret,
-    )(bigmat, bits)

@@ -1,11 +1,18 @@
-"""Public jit'd wrappers around the Pallas kernels.
+"""The one dispatcher from the coding layer to the Pallas kernels.
 
-These own padding, bit-plane packing, and backend dispatch: on TPU the
+:func:`gf_matmul_bytes_batched` is the data plane's only way into the
+bit-sliced GF(2^8) matmul: ``RSCode`` calls it for every encode and
+decode.  It owns padding, bit-plane packing, the dispatch split and the
+tile (:func:`_pick_block_w`), and counts its transfers in
+``CODEC_COUNTS``; :func:`to_host` brings a result back.  On a TPU the
 kernels compile natively; everywhere else they run in interpret mode
-(exact same kernel body, Python-executed), so the whole framework is
-testable on CPU.  ``backend="ref"`` routes to the pure-jnp oracles.
-:func:`dataplane_backend` picks the RS data plane's default backend from
-the platform: the kernels on a TPU, the numpy LUT path elsewhere.
+(the same kernel body, Python-executed), so all of it is testable on a
+CPU.  :func:`dataplane_backend` is the one place that chooses between
+the kernels and the host LUT path: the kernels on a TPU, numpy elsewhere.
+
+:func:`gf_scale_streams` and :func:`xor_reduce_bytes_batched` are the
+paper's streaming aggregation (``erasure.stream_encode``), the data-node
+scaling and the parity-node XOR fold.
 """
 
 from __future__ import annotations
@@ -20,7 +27,6 @@ from repro.core import gf256
 from repro.kernels import ref
 from repro.kernels.gf256_encode import (
     gf_matmul_bitsliced_batched,
-    gf_matmul_mxu,
     gf_scale_bitsliced,
 )
 from repro.kernels.xor_reduce import xor_reduce_batched as _xor_reduce_batched
@@ -103,10 +109,9 @@ def _clamp_block_w(words: int) -> int:
     return max(granule, min(2048, -(-words // granule) * granule))
 
 
-def _pick_block_w(length: int, block_w: int | None) -> int:
-    """Tile for an ``length``-byte chunk (32 bytes/packed word): the
-    explicit value when given, else adaptive."""
-    return block_w if block_w is not None else _clamp_block_w(-(-length // 32))
+def _pick_block_w(length: int) -> int:
+    """Tile for a ``length``-byte chunk (32 bytes/packed word)."""
+    return _clamp_block_w(-(-length // 32))
 
 
 @functools.partial(jax.jit, static_argnames=("block_w", "interpret"))
@@ -154,16 +159,14 @@ def _dispatch_sizes(s: int, per_stripe: int) -> list[int]:
 def gf_matmul_bytes_batched(
     coeffs: np.ndarray | jax.Array,
     data: np.ndarray | jax.Array,
-    backend: str = "pallas",
-    block_w: int | None = None,
 ) -> jax.Array:
     """(n, k) GF coefficient bytes x (S, k, L) stripe batch -> (S, n, L).
 
-    The batched workhorse: stripes share one coefficient upload and one
-    fused pack/matmul/unpack dispatch per :func:`_dispatch_sizes` piece
-    instead of S per-stripe round trips; host arrays move to the device
-    one piece at a time.  ``block_w=None`` picks the tile adaptively from
-    L (a multiple of the lane granule, capped at 2048 words).
+    Used for both encode (coeffs = parity matrix) and decode (coeffs =
+    inverted generator submatrix).  Stripes share one coefficient upload
+    and one fused pack/matmul/unpack dispatch per :func:`_dispatch_sizes`
+    piece; host arrays move to the device one piece at a time.  The tile
+    is :func:`_pick_block_w` of L.
     """
     if not isinstance(data, jax.Array):
         data = np.asarray(data, dtype=np.uint8)
@@ -174,10 +177,7 @@ def gf_matmul_bytes_batched(
     assert kk == k, (coeffs_np.shape, data.shape)
     if n == 0 or s == 0:
         return jnp.zeros((s, n, length), dtype=jnp.uint8)
-    if backend == "ref":
-        return ref.gf_matmul_batched_ref(
-            jnp.asarray(coeffs_np), jnp.asarray(data, dtype=jnp.uint8))
-    bw = _pick_block_w(length, block_w)
+    bw = _pick_block_w(length)
     padded = length % (32 * bw) != 0
     bitmat = _bitmat_device(coeffs_np.tobytes(), n, k)
     outs, lo = [], 0
@@ -201,28 +201,10 @@ def gf_matmul_program(n: int, k: int, s: int, length: int, sharding=None):
     dispatches for an (n, k) coefficient matrix and an (s, k, L) piece,
     lowered for this platform (or for ``sharding``'s device) but not run:
     its text names ``tpu_custom_call`` when the kernel compiles natively."""
-    bw = _pick_block_w(length, None)
+    bw = _pick_block_w(length)
     bitmat = jax.ShapeDtypeStruct((n, k, 8, 8), jnp.uint32, sharding=sharding)
     data = jax.ShapeDtypeStruct((s, k, length), jnp.uint8, sharding=sharding)
     return _encode_planes_batched.lower(bitmat, data, bw, _interpret())
-
-
-def rs_encode_stripes(
-    data: jax.Array,
-    k: int,
-    m: int,
-    kind: str = "cauchy",
-    backend: str = "pallas",
-    block_w: int | None = None,
-) -> jax.Array:
-    """Batched systematic RS(k, m): (S, k, L) uint8 -> (S, m, L) parity.
-
-    One kernel launch for the whole stripe batch — the data-plane shape the
-    paper's NIC pipeline sustains when many object writes stream through
-    concurrently.
-    """
-    parity = gf256.generator_matrix(k, m, kind)[k:]
-    return gf_matmul_bytes_batched(parity, data, backend=backend, block_w=block_w)
 
 
 @functools.partial(jax.jit, static_argnames=("block_w", "interpret"))
@@ -239,7 +221,6 @@ def _scale_planes(bitmat, data_bytes, block_w, interpret):
 def gf_scale_streams(
     coeffs: np.ndarray | jax.Array,
     data: jax.Array,
-    block_w: int | None = None,
 ) -> jax.Array:
     """(m, k) GF coefficients x (k, L) chunks -> (m, k, L) scaled streams.
 
@@ -254,74 +235,11 @@ def gf_scale_streams(
     assert data.shape[0] == k, (coeffs_np.shape, data.shape)
     if m == 0:
         return jnp.zeros((0, k, data.shape[1]), dtype=jnp.uint8)
-    bw = _pick_block_w(data.shape[1], block_w)
+    bw = _pick_block_w(data.shape[1])
     data_p, orig = _pad_to(data, 32 * bw, axis=1)
     bitmat = _bitmat_device(coeffs_np.tobytes(), m, k)
     out = _scale_planes(bitmat, data_p, bw, _interpret())
     return out[:, :, :orig]
-
-
-def gf_matmul_bytes(
-    coeffs: np.ndarray | jax.Array,
-    data: jax.Array,
-    backend: str = "pallas",
-    block_w: int | None = 1024,
-) -> jax.Array:
-    """(n, k) GF coefficient bytes x (k, L) byte rows -> (n, L).
-
-    Single-stripe wrapper over :func:`gf_matmul_bytes_batched` (S=1); used
-    for both encode (coeffs = parity matrix) and decode (coeffs = inverted
-    generator submatrix).
-    """
-    data = jnp.asarray(data, dtype=jnp.uint8)
-    return gf_matmul_bytes_batched(
-        coeffs, data[None], backend=backend, block_w=block_w
-    )[0]
-
-
-def rs_encode(
-    data: jax.Array,
-    k: int,
-    m: int,
-    kind: str = "cauchy",
-    backend: str = "pallas",
-    block_w: int | None = 1024,
-) -> jax.Array:
-    """Systematic RS(k, m) parity: (k, L) uint8 -> (m, L) uint8."""
-    parity = gf256.generator_matrix(k, m, kind)[k:]
-    return gf_matmul_bytes(parity, data, backend=backend, block_w=block_w)
-
-
-def rs_encode_mxu(
-    data: jax.Array,
-    k: int,
-    m: int,
-    kind: str = "cauchy",
-    block_n: int = 512,
-) -> jax.Array:
-    """MXU-path RS encode (beyond-paper variant; see gf256_encode.py).
-
-    Unpacks bytes to one-bit int8 columns, multiplies by the (8m, 8k) block
-    bit-matrix on the MXU, packs back.  Bit layout: column t holds byte t of
-    the stripe; rows j*8+b = bit b of chunk j.
-    """
-    data = jnp.asarray(data, dtype=jnp.uint8)
-    kk, L = data.shape
-    assert kk == k
-    parity = gf256.generator_matrix(k, m, kind)[k:]
-    bm = gf256.parity_bitmatrix(parity)              # (m, k, 8, 8)
-    # Block matrix: out-row (i*8+ob), in-col (j*8+ib).
-    big = np.transpose(bm, (0, 2, 1, 3)).reshape(8 * m, 8 * k).astype(np.int8)
-    data_p, orig = _pad_to(data, block_n, axis=1)
-    shifts = jnp.arange(8, dtype=jnp.uint8)
-    bits = ((data_p[:, None, :] >> shifts[None, :, None]) & 1).astype(jnp.int8)
-    bits = bits.reshape(8 * k, data_p.shape[1])      # (8k, Lp)
-    out_bits = gf_matmul_mxu(
-        jnp.asarray(big), bits, block_n=block_n, interpret=_interpret()
-    )
-    out_bits = out_bits.reshape(m, 8, data_p.shape[1]).astype(jnp.uint8)
-    out = (out_bits << shifts[None, :, None]).sum(axis=1).astype(jnp.uint8)
-    return out[:, :orig]
 
 
 # ---------------------------------------------------------------------------
@@ -329,32 +247,18 @@ def rs_encode_mxu(
 # ---------------------------------------------------------------------------
 
 
-def xor_reduce_bytes(x: jax.Array, backend: str = "pallas") -> jax.Array:
-    """XOR-fold (n, L) uint8 over axis 0 -> (L,) uint8.
-
-    Odd-sized payloads are zero-padded to uint32 word granularity and
-    sliced back, so every L stays on the kernel path (XOR of zero is a
-    no-op)."""
-    x = jnp.asarray(x, dtype=jnp.uint8)
-    if backend == "ref":
-        return ref.xor_reduce_ref(x)
-    return xor_reduce_bytes_batched(x[None], backend=backend)[0]
-
-
-def xor_reduce_bytes_batched(x: jax.Array, backend: str = "pallas") -> jax.Array:
+def xor_reduce_bytes_batched(x: jax.Array) -> jax.Array:
     """Batched XOR-fold: (S, n, L) uint8 over axis 1 -> (S, L) uint8.
 
     The parity-node accumulator aggregation for S concurrent sequences in
     a single 2D-grid kernel dispatch (paper section VI-B3, batched).
+    Odd-sized payloads are zero-padded to uint32 word granularity and
+    sliced back, so every L stays on the kernel path (XOR of zero is a
+    no-op).
     """
     x = jnp.asarray(x, dtype=jnp.uint8)
     assert x.ndim == 3, x.shape
     s, n, L = x.shape
-    if backend == "ref":
-        out = x[:, 0]
-        for i in range(1, n):
-            out = out ^ x[:, i]
-        return out
     xp, _ = _pad_to(x, 4, axis=2)
     words = jax.lax.bitcast_convert_type(
         xp.reshape(s, n, -1, 4), jnp.uint32
@@ -364,44 +268,3 @@ def xor_reduce_bytes_batched(x: jax.Array, backend: str = "pallas") -> jax.Array
     out = _xor_reduce_batched(words_p, block_w=bw, interpret=_interpret())[:, :orig]
     out_bytes = jax.lax.bitcast_convert_type(out[..., None], jnp.uint8)
     return out_bytes.reshape(s, -1)[:, :L]
-
-
-# ---------------------------------------------------------------------------
-# Bulk capability verification (jitted batch header-handler check).
-# ---------------------------------------------------------------------------
-
-
-@jax.jit
-def bulk_verify_tags(caps_words: jax.Array, key: jax.Array) -> jax.Array:
-    """(N, CAP_WORDS) uint32 + (4,) key -> (N, 2) uint32 tags."""
-    from repro.core.auth import sponge_mac
-
-    return sponge_mac(caps_words, key, xp=jnp)
-
-
-@jax.jit
-def bulk_verify(
-    caps_words: jax.Array, tags: jax.Array, key: jax.Array
-) -> jax.Array:
-    """Vector verdict for a batch of capabilities: (N,) bool MAC-match."""
-    want = bulk_verify_tags(caps_words, key)
-    return jnp.all(want == tags, axis=-1)
-
-
-# ---------------------------------------------------------------------------
-# Flash attention (TPU forward kernel; jnp path on CPU / for backward).
-# ---------------------------------------------------------------------------
-
-
-def flash_attention(q, k, v, causal: bool = True, backend: str | None = None):
-    """Dispatch: Pallas kernel on TPU (or backend="pallas"), jnp blockwise
-    custom-VJP path elsewhere (differentiable)."""
-    use_pallas = backend == "pallas" or (backend is None and _on_tpu())
-    if use_pallas:
-        from repro.kernels.flash_attention import flash_attention_fwd
-
-        return flash_attention_fwd(q, k, v, causal=causal,
-                                   interpret=_interpret())
-    from repro.models.attention import blockwise_attention
-
-    return blockwise_attention(q, k, v, causal, 512, 0)

@@ -27,23 +27,12 @@ def gf_mul_ref(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     return jnp.where((a == 0) | (b == 0), jnp.uint8(0), out)
 
 
-def gf_matmul_ref(coeffs: jnp.ndarray, data: jnp.ndarray) -> jnp.ndarray:
-    """GF(2^8) matmul: (m, k) coefficient bytes x (k, L) data -> (m, L).
+def gf_matmul_batched_ref(coeffs: jnp.ndarray, data: jnp.ndarray) -> jnp.ndarray:
+    """Stripe-batched LUT-path matmul: (n, k) x (S, k, L) -> (S, n, L).
 
     XOR-accumulated table-walk products — the per-byte loop the paper's
     payload handlers run on the NIC (5-7 instructions/byte), vectorized.
     """
-    coeffs = coeffs.astype(jnp.uint8)
-    data = data.astype(jnp.uint8)
-    prods = gf_mul_ref(coeffs[:, :, None], data[None, :, :])  # (m, k, L)
-    out = prods[:, 0, :]
-    for j in range(1, data.shape[0]):
-        out = out ^ prods[:, j, :]
-    return out
-
-
-def gf_matmul_batched_ref(coeffs: jnp.ndarray, data: jnp.ndarray) -> jnp.ndarray:
-    """Stripe-batched LUT-path matmul: (n, k) x (S, k, L) -> (S, n, L)."""
     coeffs = coeffs.astype(jnp.uint8)
     data = data.astype(jnp.uint8)
     prods = gf_mul_ref(coeffs[None, :, :, None], data[:, None, :, :])  # (S, n, k, L)
@@ -51,12 +40,6 @@ def gf_matmul_batched_ref(coeffs: jnp.ndarray, data: jnp.ndarray) -> jnp.ndarray
     for j in range(1, data.shape[1]):
         out = out ^ prods[:, :, j, :]
     return out
-
-
-def rs_encode_ref(data: jnp.ndarray, k: int, m: int, kind: str = "cauchy") -> jnp.ndarray:
-    """(k, L) uint8 -> (m, L) parity via the LUT path."""
-    parity = jnp.asarray(gf256.generator_matrix(k, m, kind)[k:])
-    return gf_matmul_ref(parity, data)
 
 
 def xor_reduce_ref(x: jnp.ndarray) -> jnp.ndarray:

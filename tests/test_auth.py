@@ -110,18 +110,3 @@ def test_verify_checks_the_mac_every_time():
     assert AUTH.verifications == checks + 5
     assert np.array_equal(cap.words(), cap.words())
     assert cap.words() is not cap.words()
-
-
-def test_bulk_verify_kernel():
-    from repro.kernels import ops
-
-    caps = [_cap(client_id=i, nonce=i) for i in range(16)]
-    w = np.stack([c.words() for c in caps])
-    t = np.array([c.tag for c in caps], dtype=np.uint32)
-    ok = np.asarray(ops.bulk_verify(jnp.asarray(w), jnp.asarray(t),
-                                    jnp.asarray(AUTH.key)))
-    assert ok.all()
-    t[3, 1] ^= 1
-    ok2 = np.asarray(ops.bulk_verify(jnp.asarray(w), jnp.asarray(t),
-                                     jnp.asarray(AUTH.key)))
-    assert not ok2[3] and ok2.sum() == 15

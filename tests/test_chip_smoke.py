@@ -106,7 +106,7 @@ def test_served_path_reaches_kernels_on_tpu(monkeypatch):
     which cannot compile for a TPU here)."""
     calls = []
 
-    def kernel_stub(coeffs, data, backend="pallas", block_w=None):
+    def kernel_stub(coeffs, data):
         coeffs, data = np.asarray(coeffs, np.uint8), np.asarray(data, np.uint8)
         calls.append(coeffs.shape)
         s, k, length = data.shape

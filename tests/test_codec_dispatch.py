@@ -15,7 +15,7 @@ PARITY = RSCode(6, 3).parity_matrix
 
 
 def _tile(length: int) -> int:
-    return 32 * ops._pick_block_w(length, None)
+    return 32 * ops._pick_block_w(length)
 
 
 def _check(data: np.ndarray, coeffs: np.ndarray = PARITY) -> None:

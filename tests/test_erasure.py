@@ -36,6 +36,21 @@ def test_any_m_losses_recover(k, m, length, rnd):
     assert np.array_equal(code.decode(degraded), data)
 
 
+@pytest.mark.parametrize("backend", ["numpy", "jax"])
+def test_one_stripe_views_equal_the_stripes_path(backend):
+    """``encode`` / ``decode`` are the S = 1 views of ``encode_stripes`` /
+    ``decode_stripes``, on the host LUT path and on the kernels."""
+    code = RSCode(6, 3)
+    data = np.random.default_rng(6).integers(0, 256, (6, 3901), np.uint8)
+    parity = code.encode_stripes(data[None], backend=backend)[0]
+    assert np.array_equal(code.encode(data, backend=backend), parity)
+    shards = [None, *data[1:5], None, *parity]
+    want = code.decode_stripes(
+        [None if s is None else s[None] for s in shards], backend=backend)[0]
+    assert np.array_equal(want, data)
+    assert np.array_equal(code.decode(shards, backend=backend), want)
+
+
 def test_more_than_m_losses_fail():
     code = RSCode(4, 2)
     data = np.arange(4 * 64, dtype=np.uint8).reshape(4, 64)
@@ -162,7 +177,7 @@ def test_decode_stripes_jax_backend_matches_numpy():
     st.randoms(use_true_random=False),
 )
 def test_xor_reduce_bytes_aggregates_parity_reconstruction(km, length, rnd):
-    """The parity-node XOR aggregation (kernel xor_reduce_bytes over the k
+    """The parity-node XOR aggregation (kernel XOR fold over the k
     scaled intermediate-parity streams) equals reconstructing that parity
     shard from the surviving k — the streaming dataflow and the decode
     solver agree at the erasure boundary, for odd chunk sizes."""
@@ -175,7 +190,7 @@ def test_xor_reduce_bytes_aggregates_parity_reconstruction(km, length, rnd):
     data = rng.integers(0, 256, (k, length), dtype=np.uint8)
     i = rnd.randrange(m)
     inter = gf256.gf_mul_vec(data, code.parity_matrix[i][:, None])  # (k, L)
-    agg = np.asarray(ops.xor_reduce_bytes(inter))
+    agg = np.asarray(ops.xor_reduce_bytes_batched(inter[None]))[0]
     shards = list(data) + list(code.encode(data))
     shards[k + i] = None
     assert np.array_equal(agg, code.reconstruct_shard(shards, k + i))

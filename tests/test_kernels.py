@@ -6,6 +6,10 @@ The (k, m) matrix covers the paper's schemes — RS(3,2) and RS(6,3) — plus
 the minimal RS(2,1); jit caching is maximized by reusing static configs.
 """
 
+import os
+import subprocess
+import sys
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -31,7 +35,7 @@ def test_rs_encode_pallas_matches_numpy(k, m, length):
     rng = np.random.default_rng(k * 100 + m)
     data = rng.integers(0, 256, (k, length), dtype=np.uint8)
     want = RSCode(k, m).encode(data)
-    got = np.asarray(ops.rs_encode(jnp.asarray(data), k, m, block_w=8))
+    got = RSCode(k, m).encode_stripes(data[None], backend="jax")[0]
     assert np.array_equal(got, want)
 
 
@@ -40,17 +44,11 @@ def test_rs_encode_block_shape_sweep(block_w):
     k, m = 3, 2
     rng = np.random.default_rng(block_w)
     data = rng.integers(0, 256, (k, 32 * block_w * 2), dtype=np.uint8)
-    want = RSCode(k, m).encode(data)
-    got = np.asarray(ops.rs_encode(jnp.asarray(data), k, m, block_w=block_w))
-    assert np.array_equal(got, want)
-
-
-@pytest.mark.parametrize("k,m", [(3, 2), (6, 3)])
-def test_rs_encode_mxu_variant(k, m):
-    rng = np.random.default_rng(7)
-    data = rng.integers(0, 256, (k, 1000), dtype=np.uint8)
-    want = RSCode(k, m).encode(data)
-    got = np.asarray(ops.rs_encode_mxu(jnp.asarray(data), k, m, block_n=128))
+    code = RSCode(k, m)
+    want = code.encode(data)
+    bitmat = ops._bitmat_device(code.parity_matrix.tobytes(), m, k)
+    got = np.asarray(ops._encode_planes_batched(
+        bitmat, jnp.asarray(data[None]), block_w, ops._interpret()))[0]
     assert np.array_equal(got, want)
 
 
@@ -117,8 +115,22 @@ def test_xor_reduce(n, length):
     want = x[0].copy()
     for i in range(1, n):
         want ^= x[i]
-    got = np.asarray(ops.xor_reduce_bytes(jnp.asarray(x)))
+    got = np.asarray(ops.xor_reduce_bytes_batched(jnp.asarray(x)[None]))[0]
     assert np.array_equal(got, want)
+
+
+def test_kernels_import_no_model_module():
+    """The kernels layer stands below the model scaffold: importing the
+    dispatcher in a fresh interpreter loads no ``repro.models`` module."""
+    probe = ("import sys, repro.kernels.ops; print(sorted(m for m in "
+             "sys.modules if m.startswith('repro.models')))")
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    env = dict(os.environ, PYTHONPATH=os.path.abspath(src),
+               JAX_PLATFORMS="cpu")
+    out = subprocess.run([sys.executable, "-c", probe], env=env,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.split() == ["[]"]
 
 
 @settings(max_examples=6, deadline=None)
@@ -129,7 +141,7 @@ def test_rs_encode_property_lengths(length):
     rng = np.random.default_rng(length)
     data = rng.integers(0, 256, (3, length), dtype=np.uint8)
     want = RSCode(3, 2).encode(data)
-    got = np.asarray(ops.rs_encode(jnp.asarray(data), 3, 2, block_w=8))
+    got = RSCode(3, 2).encode_stripes(data[None], backend="jax")[0]
     assert np.array_equal(got, want)
 
 

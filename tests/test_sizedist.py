@@ -13,6 +13,7 @@ scenarios; these pin its contract directly:
     seeds diverge (the workload engine's reproducibility rests on this)
 """
 
+import math
 import random
 
 import pytest
@@ -77,11 +78,14 @@ def test_bimodal_mixture_fraction(p_large, seed):
                     p_large=p_large)
     xs = _samples(dist, seed)
     assert set(xs) <= {4 * KiB, 256 * KiB}
-    frac = sum(x == 256 * KiB for x in xs) / len(xs)
-    assert abs(frac - p_large) < 0.04
-    emp = sum(xs) / len(xs)
-    want = p_large * 256 * KiB + (1 - p_large) * 4 * KiB
-    assert 0.85 * want <= emp <= 1.15 * want
+    large = sum(x == 256 * KiB for x in xs)
+    frac = large / len(xs)
+    # the count of large draws is binomial: 5 sigma is a false alarm once
+    # in ~10^6 examples, and tighter than a fixed band at every p_large
+    assert abs(frac - p_large) < 5 * math.sqrt(
+        p_large * (1 - p_large) / len(xs))
+    # with two sizes the empirical mean is fixed by the observed fraction
+    assert sum(xs) == large * 256 * KiB + (len(xs) - large) * 4 * KiB
 
 
 # -- (c) fixed ---------------------------------------------------------------

@@ -66,7 +66,7 @@ def test_off_tile_chunk_program_compiles(one_chip, length):
     """RS(6,3) encode of a chunk off the kernel tile (an mdtest-hard
     file's 3,904-byte chunk, a checkpoint leaf's 341 kB one): the pad and
     the trim compile inside the one program the dispatch runs."""
-    assert length % (32 * ops._pick_block_w(length, None))
+    assert length % (32 * ops._pick_block_w(length))
     assert _has_kernel(ops.gf_matmul_program(3, 6, 1, length,
                                              sharding=one_chip))
 
@@ -76,7 +76,7 @@ def test_rs63_kernel_compiles_at_1mib_cell(one_chip, n, k):
     """RS(6,3) encode and the 6x6 decode at HDFS's 1 MiB cell, with the
     tile and the dispatch size the data plane picks for it."""
     length = 1 * MiB
-    bw = ops._pick_block_w(length, None)
+    bw = ops._pick_block_w(length)
     assert bw % 128 == 0
     s = ops._dispatch_sizes(1000, (k + n) * length)[0]
     bitmat = jax.ShapeDtypeStruct((n, k, 8, 8), jnp.uint32, sharding=one_chip)
@@ -90,7 +90,7 @@ def test_rs63_kernel_compiles_at_1mib_cell(one_chip, n, k):
 def test_gf_scale_program_compiles(one_chip):
     code = RSCode(6, 3)
     length = 4096
-    bw = ops._pick_block_w(length, None)
+    bw = ops._pick_block_w(length)
     bitmat = jax.ShapeDtypeStruct((3, 6, 8, 8), jnp.uint32, sharding=one_chip)
     data = jax.ShapeDtypeStruct((code.k, length), jnp.uint8, sharding=one_chip)
     assert _has_kernel(
